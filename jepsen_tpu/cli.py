@@ -464,7 +464,7 @@ def run_cli(test_fn: Optional[Callable[[Dict], Dict]] = None,
     if raw[:1] == ["probe"]:
         # same pre-parse forwarding as lint: jepsen_tpu.probe owns its
         # flags and the 0/1/2 healthy/wedged/no-backend contract (the
-        # r05 runbook's automation hook — see docs/observability.md)
+        # runbook's automation hook — see docs/observability.md)
         from jepsen_tpu import probe
         return probe.main(raw[1:])
     if raw[:1] == ["status"]:
@@ -497,6 +497,10 @@ def run_cli(test_fn: Optional[Callable[[Dict], Dict]] = None,
     if args.command is None:
         parser.print_help()
         return EXIT_BAD_ARGS
+    # test / analyze / serve compile device programs; the subcommands
+    # forwarded above never touch jax
+    from jepsen_tpu.compile_cache import enable_compile_cache
+    enable_compile_cache()
     try:
         if args.command == "test":
             return run_test_cmd(test_fn, args)

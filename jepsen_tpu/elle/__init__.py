@@ -1,6 +1,6 @@
 """Transactional-anomaly cycle checker — the elle 0.1.2 capability rebuilt
 TPU-first (reference call surface: jepsen/src/jepsen/tests/cycle.clj,
-tests/cycle/append.clj, tests/cycle/wr.clj; anomaly taxonomy documented at
+tests/cycle/append.clj, tests/cycle/wr.clj; anomaly classification documented at
 tests/cycle/wr.clj:31-45).
 
 Transactions become nodes in a dependency graph with typed edges:
@@ -11,7 +11,7 @@ Transactions become nodes in a dependency graph with typed edges:
   rt  realtime      T1 completed before T2 was invoked
   p   process       T1 preceded T2 on the same process
 
-Anomalies are cycles in restricted subgraphs (Adya's taxonomy):
+Anomalies are cycles in restricted subgraphs (Adya's classification):
 
   G0        cycle of only ww edges
   G1c       cycle of ww+wr edges (at least one wr)

@@ -1,5 +1,5 @@
 """Write/read register transactional checker (elle.rw-register capability;
-call surface jepsen/src/jepsen/tests/cycle/wr.clj:9-54, anomaly taxonomy
+call surface jepsen/src/jepsen/tests/cycle/wr.clj:9-54, anomaly classification
 documented there).
 
 Writes are unique per key, so write-read dependencies are exact: reading

@@ -293,8 +293,7 @@ def env_path(name: str, what: str = "path") -> Optional[str]:
 #                            (loads are version/fingerprint-guarded:
 #                            a mismatch degrades to a fresh compile,
 #                            counted load_errors — never a wrong
-#                            program); bench.py reuses the same dir
-#                            for its jax compilation cache
+#                            program)
 #   JEPSEN_TPU_CANON_SHAPES  env_bool    parallel.programs — shape
 #                            canonicalization: quantize one-shot and
 #                            resumable-chunk event-row counts onto the
@@ -462,7 +461,7 @@ def env_path(name: str, what: str = "path") -> Optional[str]:
 #                            byte-identical to the pre-ops service);
 #                            `--ops-port` overrides
 #   JEPSEN_TPU_PROBE_INTERVAL env_float  probe — continuous chip
-#                            watch: re-run the subprocess probe_json
+#                            watch: re-run probe.check_in_process
 #                            every N seconds on a daemon thread and
 #                            publish probe.chip_healthy /
 #                            probe.last_ok_age_secs gauges (feeding

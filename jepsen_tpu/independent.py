@@ -457,10 +457,6 @@ class IndependentChecker(Checker):
                    else "dispatch-error")
             return None, self._fallback(
                 cls, f"{type(err).__name__}: {err}", backend)
-        except Exception as err:  # noqa: BLE001 - host path still checks
-            return None, self._fallback(
-                "dispatch-error", f"{type(err).__name__}: {err}",
-                backend)
 
     @staticmethod
     def _fallback(cls: str, reason: str, backend, skip=False) -> dict:

@@ -260,7 +260,7 @@ class PackedSpec:
     # .crashed), including the interning ORDER (encode() must produce
     # the same arrays whichever path runs). Exists because the
     # per-call Python loop is the measured constant on the batched
-    # end-to-end path (PERF_R05: encode-bound, not search-bound); the
+    # end-to-end path (round 5 on chip: encode-bound, not search-bound); the
     # bulk form preallocates the arrays and keeps the dispatch
     # overhead to one call per history instead of one per op.
     encode_calls: Callable = None

@@ -137,8 +137,7 @@ def pack_lanes(pack, C: int) -> int:
 def _resolve_sparse_pallas(sparse_pallas, N: int, C: int, platform: str,
                            dedupe: str, pack=()):
     """The sparse engine's fused-frontier-kernel gate -> (mode, note)
-    with mode one of "off" / "on" / "interpret" / "tiled" /
-    "tiled-interpret".
+    with mode one of "off" / "interpret" / "tiled-interpret".
 
     `sparse_pallas` None defers to the strict tri-state
     JEPSEN_TPU_SPARSE_PALLAS flag (default OFF until a chip A/B
@@ -152,10 +151,15 @@ def _resolve_sparse_pallas(sparse_pallas, N: int, C: int, platform: str,
     at ~3x the capacity. A shape past the whole-event fusion gate no
     longer degrades wholesale: it runs the TILED closure
     (sparse_kernels.tiled_insert_call — the hash table streams
-    HBM<->VMEM in double-buffered tiles, mode "tiled"), and only a
+    HBM<->VMEM in double-buffered tiles, mode "tiled-interpret"), and only a
     shape past the tiled planner too falls back to the XLA hash
-    closure with a note (the bitdense mesh-fallback precedent: the
-    default path degrades, never errors)."""
+    closure with a note.
+
+    On a TPU the kernels are refused with NotImplementedError: the
+    Mosaic compiler rejects their in-kernel 1-D gathers ("Only 2D
+    gather is supported"), so they run only in interpret mode, off the
+    chip — never interpreted on a chip, never swapped for XLA
+    there."""
     if dedupe != "hash":
         if sparse_pallas:
             raise ValueError(
@@ -180,12 +184,18 @@ def _resolve_sparse_pallas(sparse_pallas, N: int, C: int, platform: str,
         return "off", None
     from jepsen_tpu.parallel import sparse_kernels as sk
     from jepsen_tpu.parallel.bitdense import is_tpu_platform
+    if is_tpu_platform(platform):
+        raise NotImplementedError(
+            "JEPSEN_TPU_SPARSE_PALLAS: the fused sparse frontier "
+            "kernels do not compile for a TPU (Mosaic: \"Only 2D gather "
+            "is supported\" for their in-kernel 1-D gathers); they run "
+            "only in interpret mode off the chip. Unset the flag to use "
+            "the XLA hash closure.")
     lanes = pack_lanes(pack, C)
-    on_tpu = is_tpu_platform(platform)
     if sk.supported(N, C, lanes):
-        return ("on" if on_tpu else "interpret"), None
+        return "interpret", None
     if sk.tiled_plan(N, C, lanes) is not None:
-        return ("tiled" if on_tpu else "tiled-interpret"), None
+        return "tiled-interpret", None
     obs.counter("engine.sparse_pallas_fallbacks").inc()
     note = (f"sparse frontier kernel skipped at capacity {N} "
             f"(C={C}, {lanes} row lanes): probe state would exceed "
@@ -2171,7 +2181,13 @@ def extract_final_paths(model, e: EncodedHistory, fail_r: int,
         seeds = [(e.state0, frozenset())]
         occupants: dict = {}
     else:
-        rows = _frontier_at(e, start_ev)
+        from jepsen_tpu.parallel import bitdense
+        if bitdense.fits_bitdense(bitdense.n_states(e), e.n_slots):
+            # the engine that decided this history: its dense tensor is
+            # the complete frontier, with no capacity to escalate
+            rows = bitdense.frontier_rows(e, start_ev)
+        else:
+            rows = _frontier_at(e, start_ev)
         if rows is None:
             return _empty("seed-frontier re-scan overflowed max capacity")
         occupants = _slot_occupants_before(e, start_ev)

@@ -1,7 +1,8 @@
 """Pipelined multi-key checking: overlap host encode, H2D transfer,
 and device search.
 
-PERF_R05's on-chip numbers showed the batched checker is no longer
+The round-5 on-chip numbers (bench_results/bench_r05_onchip.jsonl)
+showed the batched checker is no longer
 search-bound: device-only throughput beat end-to-end by ~7%, and the
 whole gap is the HOST phase — `check_batch` encoded every key serially
 in Python before the first device dispatch, so the TPU sat idle

@@ -242,6 +242,29 @@ def _no_persistent_cache():
         return contextlib.nullcontext()
 
 
+# XLA:CPU cannot serialize an executable that has already run (a sort
+# comparator is built on first use: "`LessThan` is not serializable"),
+# and jax hands one in-memory executable to every compile of the same
+# program. So a registry that persists compiles with an option (at its
+# default value) that keeps its programs apart from every other compile
+# in the process, and each program is serialized on its first persist,
+# before it has run, and the bytes reused.
+_PERSIST_COMPILE_OPTIONS = {"xla_embed_ir_in_executable": False}
+_PAYLOADS: Dict[str, tuple] = {}
+_PAYLOADS_LOCK = threading.Lock()
+
+
+def _serialize(digest: str, compiled) -> tuple:
+    with _PAYLOADS_LOCK:
+        payload = _PAYLOADS.get(digest)
+    if payload is None:
+        from jax.experimental import serialize_executable as se
+        payload = se.serialize(compiled)
+        with _PAYLOADS_LOCK:
+            _PAYLOADS[digest] = payload
+    return payload
+
+
 class _Program:
     __slots__ = ("compiled", "spec", "aot")
 
@@ -358,7 +381,9 @@ class ProgramRegistry:
             t0 = perf_counter()
             with obs.span("serve.compile", program=name,
                           digest=digest), _no_persistent_cache():
-                compiled = entry.lower(*shapes, **kw).compile()
+                compiled = entry.lower(*shapes, **kw).compile(
+                    compiler_options=(_PERSIST_COMPILE_OPTIONS
+                                      if self.cache_dir else None))
             dt = perf_counter() - t0
             self._count("compiles")
             obs.histogram("serve.compile_secs").observe(dt)
@@ -390,10 +415,11 @@ class ProgramRegistry:
         if not self.cache_dir:
             return
         try:
-            from jax.experimental import serialize_executable as se
-            payload = se.serialize(compiled)
+            payload = _serialize(digest, compiled)
+            devices = compiled.runtime_executable().local_devices()
             blob = {"fingerprint": self._fingerprint(digest),
-                    "payload": payload}
+                    "payload": payload,
+                    "devices": [d.id for d in devices]}
             os.makedirs(self.cache_dir, exist_ok=True)
             path = self._path(digest)
             tmp = f"{path}.tmp.{os.getpid()}"
@@ -422,11 +448,18 @@ class ProgramRegistry:
             if fp != want:
                 raise ValueError(
                     f"fingerprint mismatch: cached {fp} != {want}")
+            import jax
             from jax.experimental import serialize_executable as se
+            by_id = {d.id: d for d in jax.devices()}
+            # the devices the program was compiled for: without them
+            # the loader assumes every device of the backend, and a
+            # one-device program then refuses its one-shard arguments
+            devices = [by_id[i] for i in blob["devices"]]
             t0 = perf_counter()
             with obs.span("serve.compile", program="preload",
                           digest=digest):
-                compiled = se.deserialize_and_load(*blob["payload"])
+                compiled = se.deserialize_and_load(
+                    *blob["payload"], execution_devices=devices)
             obs.histogram("serve.compile_secs").observe(
                 perf_counter() - t0)
             self._count("preloads")

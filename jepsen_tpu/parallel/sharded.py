@@ -75,20 +75,6 @@ from jepsen_tpu.resilience import supervisor as sup
 _log = logging.getLogger(__name__)
 
 
-def _shard_map(f, mesh, in_specs, out_specs, check_vma=False):
-    """jax.shard_map across jax versions: the top-level name landed
-    after 0.4.x — older builds (this image's 0.4.37 among them) carry
-    it as jax.experimental.shard_map.shard_map with the replication
-    check named check_rep instead of check_vma. Every sharded entry
-    point routes through here so the engine runs on both."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as esm
-    return esm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
-
-
 def _owned_dedupe_compact(rows, live, Nd, n_dev, my_idx, rep):
     """Keep rows owned by this device, sort-dedupe, compact to [Nd].
     Lane-generic: rows is the representation's lane tuple (the
@@ -545,7 +531,7 @@ def _check_sharded2d(xs, state0, step_name: str, Nd: int, n_slice: int,
     if search_stats:
         out_specs = out_specs + (
             _stats_out_specs((AX_SLICE, AX_CHIP)),)
-    fn = _shard_map(
+    fn = jax.shard_map(
         lambda x, s0: _sharded2d_impl(x, s0, step_name, Nd, n_slice,
                                       n_chip, dedupe, probe_limit,
                                       sparse_pallas, search_stats,
@@ -573,7 +559,7 @@ def _check_sharded(xs, state0, step_name: str, Nd: int, n_dev: int,
     out_specs = (P(), P(), P(), P(), P())
     if search_stats:
         out_specs = out_specs + (_stats_out_specs(AXIS),)
-    fn = _shard_map(
+    fn = jax.shard_map(
         lambda x, s0: _sharded_impl(x, s0, step_name, Nd, n_dev, exchange,
                                     dedupe, probe_limit, sparse_pallas,
                                     search_stats, pack),
@@ -644,7 +630,7 @@ def _check_sharded_resume(xs, carry, step_name: str, Nd: int,
     L = pack_lanes(pack, xs["slot_f"].shape[1])
     carry_specs = tuple([P(AXIS)] * L) + (P(AXIS),) \
         + tuple([P()] * 5)
-    fn = _shard_map(
+    fn = jax.shard_map(
         lambda x, c: _sharded_resume_impl(x, c, step_name, Nd, n_dev,
                                           dedupe, probe_limit,
                                           sparse_pallas, pack),
@@ -737,7 +723,7 @@ def _check_sharded_resume2d(xs, carry, step_name: str, Nd: int,
     dev_axes = (AX_SLICE, AX_CHIP)
     carry_specs = tuple([P(dev_axes)] * L) + (P(dev_axes),) \
         + tuple([P()] * 5)
-    fn = _shard_map(
+    fn = jax.shard_map(
         lambda x, c: _sharded_resume2d_impl(x, c, step_name, Nd,
                                             n_slice, n_chip, dedupe,
                                             probe_limit, sparse_pallas,
@@ -974,7 +960,7 @@ def _resolve_sparse_pallas(sparse_pallas, Nd: int, C: int, n_chip: int,
     mode, _ = engine_resolve(sparse_pallas, 1, 1, platform, dedupe)
     if mode == "off":
         return mode, None
-    mode = "on" if mode in ("on", "tiled") else "interpret"
+    mode = "interpret"   # the resolver refuses the kernels on a TPU
     n_dev = n_chip * n_slice
     if exchange == "gather":
         M = n_dev * Nd * C

@@ -13,13 +13,14 @@ the containment layer between the checker engines and JAX:
   supervisor   every device dispatch site runs through
                ``dispatch(site, thunk, backend=...)``: watchdog-
                bounded wait (``DispatchWedged`` instead of a hung
-               process), breaker bookkeeping, transient-failure
-               retries; a test-pinned near-zero-overhead passthrough
-               when nothing is active
+               process), breaker bookkeeping, retries of runtime
+               errors (compile errors propagate unchanged); a
+               test-pinned near-zero-overhead passthrough when
+               nothing is active
   breaker      per-backend circuit breaker (closed -> open on
                consecutive failures, exponential backoff with jitter,
-               half-open recovery probing via the ``jepsen probe``
-               subprocess contract)
+               half-open recovery probing in this process,
+               ``probe.check_in_process``)
   recovery     verdict-preserving degradation: whole-key host WGL
                re-checks and FrontierCheckpoint host resumes, each
                tagged with a structured ``resilience`` result note

@@ -11,12 +11,12 @@ backend was down. The breaker is that memory:
                until an exponential backoff (base doubling per
                re-open, deterministic jitter, capped) elapses
     half-open  one caller per window runs the recovery probe —
-               ``jepsen_tpu.probe``'s subprocess ``jax.devices()``
-               check, so the parent process NEVER touches the possibly
-               wedged runtime directly (the probe child takes the
-               hang, exactly as the r05 runbook did by hand). A
-               healthy probe closes the breaker; anything else
-               re-opens it with a doubled backoff.
+               ``jepsen_tpu.probe.check_in_process``: one tiny op on
+               the backend this process holds, under a watchdog
+               thread that takes any hang. It never starts a child: a
+               chip belongs to one process at a time, and this one
+               holds it. A healthy probe closes the breaker; anything
+               else re-opens it with a doubled backoff.
 
 Clock, probe, and jitter are injectable (fake-clock lifecycle tests);
 defaults come from the validated ``JEPSEN_TPU_BREAKER_*`` flags.
@@ -25,7 +25,8 @@ gauge (0 closed / 1 half-open / 2 open) and the
 ``resilience.breaker.opens`` counter, so a trace of a degraded run
 shows when and why dispatch stopped.
 
-Import-safe: no JAX — the probe runs in a subprocess by design.
+Import-safe: no JAX at module scope — the probe imports it when it
+runs.
 """
 
 from __future__ import annotations
@@ -47,12 +48,11 @@ PROBE_TIMEOUT_SECS = 30.0
 
 
 def _default_probe() -> bool:
-    """The half-open recovery check: the same subprocess
-    ``jax.devices()`` contract as ``jepsen probe`` (probe_json), so
-    external automation and the breaker read one health surface."""
+    """The half-open recovery check, in this process (see the module
+    docstring)."""
     from jepsen_tpu import probe
-    r = probe.probe_json(timeout=PROBE_TIMEOUT_SECS, retries=1)
-    return r["verdict"] == "healthy"
+    return probe.check_in_process(PROBE_TIMEOUT_SECS)["verdict"] \
+        == "healthy"
 
 
 def _resolve_threshold() -> int:
@@ -194,8 +194,8 @@ class CircuitBreaker:
             if self._state == CLOSED:
                 return True, ""
             if self._state == HALF_OPEN:
-                # another caller's recovery probe is in flight (a 30s
-                # subprocess in production): refuse rather than
+                # another caller's recovery probe is in flight (up to
+                # 30s in production): refuse rather than
                 # stampede the recovering runtime with N probes
                 return False, (
                     f"circuit breaker half-open for backend "

@@ -11,21 +11,23 @@ host->device transfers) runs through :func:`dispatch`. Three jobs:
      CPU.
   2. **Watchdog** (``JEPSEN_TPU_WATCHDOG=<secs>``): the dispatch runs
      on a worker thread with a bounded join. A call past the bound
-     raises :class:`DispatchWedged` — the r05 hang-forever signature
-     (a wedged PJRT runtime blocks in C with no Python-level signal,
-     see jepsen_tpu/probe.py) becomes a structured verdict instead of
-     a hung process. A REALLY wedged call cannot be cancelled; its
+     raises :class:`DispatchWedged` — a wedged device runtime (it
+     blocks in C with no Python-level signal, see jepsen_tpu/probe.py)
+     becomes a structured verdict instead of a hung process. A REALLY wedged call cannot be cancelled; its
      daemon thread is abandoned (the documented, bounded cost — the
      breaker stops the pile-up after `threshold` of them).
   3. **Circuit breaker** (``resilience.breaker``): successes and
      failures are recorded per backend; dispatch against an open
      breaker raises :class:`DeviceUnavailable` WITHOUT touching the
-     runtime, and the half-open recovery probe runs in a subprocess
-     (``jepsen_tpu.probe``) so the parent never does either.
+     runtime, and the half-open recovery check is one tiny op under a
+     watchdog thread (``jepsen_tpu.probe.check_in_process``).
 
-Transient failures (``flaky`` faults, real device exceptions) are
-retried up to ``JEPSEN_TPU_DISPATCH_RETRIES`` times while the breaker
-stays closed, under a ``resilience.retry`` span. Wedges and injected
+Errors raised while a compiled program executes
+(``jax.errors.JaxRuntimeError``, and ``flaky`` faults standing in for
+them) are retried up to ``JEPSEN_TPU_DISPATCH_RETRIES`` times while the
+breaker stays closed, under a ``resilience.retry`` span. Tracing,
+lowering and compile errors propagate unchanged: never retried, never
+counted on the breaker, never degraded to the host. Wedges and injected
 crashes are NOT retried here — re-dispatching against a wedged
 runtime piles up stuck threads, and crash recovery belongs to the
 callers' degradation contracts (host fallback / checkpoint resume in
@@ -70,8 +72,8 @@ _INJECTED_WEDGE_TIMEOUT = 0.2
 
 
 class DispatchWedged(RuntimeError):
-    """A supervised dispatch exceeded its watchdog bound — the r05
-    make_c_api_client signature, as a structured verdict."""
+    """A supervised dispatch exceeded its watchdog bound — a wedged
+    device runtime, as a structured verdict."""
 
     def __init__(self, site: str, timeout: float,
                  backend: Optional[str] = None):
@@ -129,9 +131,10 @@ def active(backend: Optional[str] = None) -> bool:
             or breaker_mod.any_tripped())
 
 
-def _run_watchdogged(thunk: Callable, timeout: float, site: str,
-                     backend: Optional[str]):
-    """Run `thunk` on a daemon worker with a bounded join."""
+def run_watchdogged(thunk: Callable, timeout: float, site: str,
+                    backend: Optional[str] = None):
+    """Run `thunk` on a daemon worker with a bounded join; past the
+    bound, raise DispatchWedged and abandon the worker."""
     box: dict = {}
 
     def worker():
@@ -148,8 +151,8 @@ def _run_watchdogged(thunk: Callable, timeout: float, site: str,
         obs.counter("resilience.watchdog_kills").inc()
         _log.warning(
             "device dispatch at site %r exceeded the %.1fs watchdog "
-            "bound — abandoning the worker thread (the r05 wedge "
-            "signature; see docs/resilience.md)", site, timeout)
+            "bound — abandoning the worker thread (a wedged device "
+            "runtime; see docs/resilience.md)", site, timeout)
         # postmortem evidence even with tracing off: the armed flight
         # recorder (JEPSEN_TPU_FLIGHT_RECORDER) dumps its span ring +
         # metric delta; unarmed, this is a single None check
@@ -167,7 +170,7 @@ def _injected_wedge(plan_event: threading.Event, site: str,
     so the worker exits instead of leaking (a real wedge can't be
     released — this seam exists precisely so tests don't need one)."""
     try:
-        _run_watchdogged(
+        run_watchdogged(
             lambda: plan_event.wait(timeout + _WEDGE_SELF_RELEASE_SECS),
             timeout, site, backend)
     finally:
@@ -188,14 +191,17 @@ def dispatch(site: str, thunk: Callable, backend: Optional[str] = None,
       DeviceUnavailable   the backend's breaker is open
       DispatchWedged      watchdog bound exceeded (injected or real)
       InjectedCrash       a `raise` fault fired
-      (original error)    a real/transient failure that survived the
-                          retry budget
+      DeviceUnavailable   a runtime error that survived the retry
+                          budget (the original rides `cause`)
+      (original error)    anything raised before the program ran:
+                          tracing, lowering, compiling
     """
     if site not in _SITES:
         raise ValueError(f"unknown dispatch site {site!r} "
                          f"(expected one of {faults.SITES})")
     if watchdog is None and retries is None and not active(backend):
         return thunk()
+    _tag_compile_errors()
 
     wd = watchdog if watchdog is not None else _resolve_watchdog()
     budget = retries if retries is not None else _resolve_retries()
@@ -231,24 +237,27 @@ def dispatch(site: str, thunk: Callable, backend: Optional[str] = None,
             raise
         except DeviceUnavailable:
             raise
-        except Exception as err:  # noqa: BLE001 — transient or real
+        except Exception as err:  # noqa: BLE001 — judged by _executing
+            if not _executing(err):
+                # tracing, lowering and compile errors: a bug, or a
+                # program this device cannot run. Never retried, never
+                # counted on the breaker, never degraded to the host —
+                # the check fails loudly with the original error.
+                raise
             blocked = br is not None and br.state != breaker_mod.CLOSED
             if attempt >= budget or blocked:
                 # ONE breaker failure per failing dispatch CALL, not
                 # per attempt: threshold N means "N failed dispatches",
                 # and a transient that recovers within its budget never
-                # counts at all — a deterministic non-runtime error
-                # (compile bug, shape bug) therefore needs N separate
-                # failing calls to open the breaker, not N/retries
+                # counts at all
                 if br is not None:
                     br.record_failure(f"{type(err).__name__}: {err}")
                 # budget exhausted (or the breaker tripped mid-retry):
                 # surface as DeviceUnavailable so the callers'
-                # degradation contract catches it — a persistent real
-                # device error (the dying-chip XlaRuntimeError mode)
-                # must degrade to the host path exactly like an
-                # injected crash, not crash the check. The original
-                # error rides `cause`/`__cause__` for diagnosis.
+                # degradation contract catches it — a persistent
+                # runtime error (the dying-chip mode) must degrade to
+                # the host path exactly like an injected crash. The
+                # original error rides `cause`/`__cause__`.
                 raise DeviceUnavailable(
                     site,
                     f"dispatch failed after {attempt + 1} attempt(s): "
@@ -256,6 +265,42 @@ def dispatch(site: str, thunk: Callable, backend: Optional[str] = None,
                     backend, cause=err) from err
             attempt += 1
             obs.counter("resilience.retries").inc()
+
+
+_COMPILE_ERROR = "_jepsen_compile_error"
+_compile_hook_lock = threading.Lock()
+_compile_hook_installed = False
+
+
+def _tag_compile_errors() -> None:
+    """XLA reports a failed compile with the same JaxRuntimeError type
+    as a failed execution. jax's compile step runs registered handlers
+    on such errors; the one installed here tags them, and changes
+    nothing else. Installed on the first supervised dispatch, so that
+    importing this module stays free of JAX."""
+    global _compile_hook_installed
+    with _compile_hook_lock:
+        if _compile_hook_installed:
+            return
+        from jax._src import compiler
+
+        def tag(err):
+            setattr(err, _COMPILE_ERROR, True)
+            return None   # propagate the original error
+
+        compiler.register_xla_runtime_error_handler(tag)
+        _compile_hook_installed = True
+
+
+def _executing(err: BaseException) -> bool:
+    """Whether `err` was raised while a compiled program executed: the
+    only failures dispatch retries, counts on the breaker and turns
+    into DeviceUnavailable. A `flaky` fault stands in for one."""
+    if isinstance(err, faults.TransientFault):
+        return True
+    from jax.errors import JaxRuntimeError
+    return (isinstance(err, JaxRuntimeError)
+            and not getattr(err, _COMPILE_ERROR, False))
 
 
 class _NullCtx:
@@ -295,7 +340,7 @@ def _one_attempt(site: str, thunk: Callable, backend: Optional[str],
             thunk = lambda: (time.sleep(delay), inner())[1]  # noqa: E731
         else:
             raise faults.TransientFault(site, rule)
-    r = (_run_watchdogged(thunk, wd, site, backend) if wd
+    r = (run_watchdogged(thunk, wd, site, backend) if wd
          else thunk())
     if br is not None:
         br.record_success()

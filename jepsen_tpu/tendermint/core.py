@@ -61,7 +61,7 @@ def _transport(test, node):
 
 @contextlib.contextmanager
 def _map_errors(o: Op, crash: str):
-    """Shared tx-error taxonomy (core.clj:57-75,116-138): code 8 ->
+    """Shared tx-error classification (core.clj:57-75,116-138): code 8 ->
     :fail precondition-failed, code 7 -> :fail not-found, connection
     refused -> :fail, other network faults -> `crash` (:info for
     writes, :fail for reads) with an indeterminate error."""

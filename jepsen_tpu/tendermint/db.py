@@ -440,7 +440,7 @@ def consensus_node(test) -> str:
 
 class _PartitionedTransport:
     """A transport on the wrong side of a grudge: every use times out.
-    Raised at USE (not open) so the clients' _map_errors taxonomy
+    Raised at USE (not open) so the clients' _map_errors classification
     applies — writes/cas surface as indeterminate :info, reads as
     :fail — exactly how a minority node's RPC behaves in the real
     cluster: the connection opens, the commit never comes."""

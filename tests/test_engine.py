@@ -965,3 +965,26 @@ def test_encode_snapshot_interval_fill_matches_naive_oracle():
                 del occupant[s]
                 hq.heappush(free, s)
         assert r == e.n_returns
+
+
+@pytest.mark.parametrize("shape", [(60, 4), (300, 8), "crashy"])
+def test_bitdense_frontier_rows_equal_the_sparse_frontier(shape):
+    """Counterexample extraction seeds its window search from the
+    bitdense reachable-set tensor when the history fits that engine
+    (one scan, no capacity escalation). The rows must be exactly the
+    sparse engine's live frontier at every cut."""
+    from jepsen_tpu.histories import (adversarial_register_history,
+                                      with_impossible_read)
+    from jepsen_tpu.parallel import bitdense
+    if shape == "crashy":
+        h = rand_register_history(n_ops=200, n_processes=6, n_values=3,
+                                  crash_p=0.05, seed=3)
+    else:
+        n, k = shape
+        h = with_impossible_read(adversarial_register_history(
+            n_ops=n, k_crashed=k, seed=7))
+    e = enc_mod.encode(CASRegister(), h)
+    for ev in (5, e.n_returns // 2, e.n_returns - 3):
+        rows = bitdense.frontier_rows(e, ev)
+        assert len(rows) == len(set(rows))
+        assert set(rows) == set(engine._frontier_at(e, ev)), ev

@@ -1,6 +1,8 @@
 """Per-key independence tests (reference:
 jepsen/test/jepsen/independent_test.clj + generator_test.clj:386-454)."""
 
+import pytest
+
 import jepsen_tpu.generator as gen
 from jepsen_tpu import independent
 from jepsen_tpu.checker import linearizable
@@ -115,25 +117,43 @@ def test_independent_checker_device_batch_with_mesh():
     assert "mesh" not in serializable_test({"mesh": mesh, "name": "t"})
 
 
-def test_device_batch_failure_is_loud(monkeypatch, caplog):
-    """A broken device path must not silently degrade to the host
-    checker: the result carries a device-fallback tag and a warning is
-    logged (the host still produces correct per-key results)."""
+def test_device_batch_dispatch_failure_is_loud(monkeypatch, caplog):
+    """A device that fails while it runs (here: a watchdog wedge) must
+    not silently degrade to the host checker: the result carries a
+    device-fallback tag and a warning is logged (the host still
+    produces correct per-key results)."""
     import logging
 
     from jepsen_tpu.parallel import engine
+    from jepsen_tpu.resilience import supervisor as sup
 
-    def boom(*a, **k):
-        raise RuntimeError("simulated TPU runtime breakage")
+    def wedged(*a, **k):
+        raise sup.DispatchWedged("dispatch", 1.0, "cpu")
 
-    monkeypatch.setattr(engine, "check_batch", boom)
+    monkeypatch.setattr(engine, "check_batch", wedged)
     c = independent.checker(linearizable(CASRegister(), algorithm="jax"))
     with caplog.at_level(logging.WARNING, logger="jepsen_tpu.independent"):
         r = c.check({}, _keyed_register_history())
     assert r["valid?"] is False          # host path still checked keys
     assert r["failures"] == ["y"]
-    assert "simulated TPU runtime breakage" in r["device-fallback"]
+    assert "watchdog bound" in r["device-fallback"]
+    assert r["resilience"]["class"] == "wedged"
     assert any("FAILED" in rec.message for rec in caplog.records)
+
+
+def test_device_batch_error_propagates(monkeypatch):
+    """Any other device-batch error (a kernel the compiler refuses, a
+    bug) is raised, not answered from the host."""
+    from jepsen_tpu.parallel import engine
+
+    def boom(*a, **k):
+        raise NotImplementedError("Mosaic kernels cannot be "
+                                  "automatically partitioned")
+
+    monkeypatch.setattr(engine, "check_batch", boom)
+    c = independent.checker(linearizable(CASRegister(), algorithm="jax"))
+    with pytest.raises(NotImplementedError, match="Mosaic"):
+        c.check({}, _keyed_register_history())
 
 
 def test_device_batch_not_applicable_is_quiet():

@@ -1,5 +1,6 @@
-"""Tests for `jepsen probe` (jepsen_tpu.probe) — the r05 runbook's
-hand-rolled device-health loop as a first-class subcommand.
+"""Tests for `jepsen probe` (jepsen_tpu.probe) — the device-health
+loop as a first-class subcommand, and the in-process check the
+breaker and the probe watch run.
 
 The wedge and no-backend paths are driven by swapping the child code
 (the same seam the runbook's real failures exercised: a child that
@@ -15,8 +16,8 @@ import pytest
 
 from jepsen_tpu import probe
 
-# one verdict line per attempt, PROBES_r05.log format: utc timestamp,
-# "probe:", verdict text
+# one verdict line per attempt (the probe module docstring's format):
+# utc timestamp, "probe:", verdict text
 _LINE = re.compile(r"^\d{4}-\d{2}-\d{2}T\d{2}:\d{2}:\d{2}Z probe: ")
 
 
@@ -154,3 +155,70 @@ def test_probe_flag_parsing(argv, expect, monkeypatch):
     monkeypatch.setattr(probe, "run_probe", fake)
     assert probe.main(argv) == 0
     assert (seen["timeout"], seen["retries"], seen["interval"]) == expect
+
+
+def test_probe_cpu_only_is_not_healthy(monkeypatch):
+    """A child that finds only the CPU backend is no-backend unless
+    JAX_PLATFORMS asked for the CPU: a TPU host whose chip is gone
+    must not read healthy. (The child is stubbed: it reports what
+    jax.devices() would, without starting a backend.)"""
+    cpu_only = """print('JEPSEN_PROBE ["cpu", 8]')"""
+    with mock.patch.object(probe, "_CHILD_CODE", cpu_only):
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+        doc = probe.probe_json(timeout=30.0, retries=3)
+        assert (doc["verdict"], doc["exit"]) == ("no-backend", 2)
+        assert "only the CPU" in doc["attempts"][-1]["err"]
+        monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+        doc = probe.probe_json(timeout=30.0, retries=1)
+        assert doc["verdict"] == "healthy" and doc["n_devices"] == 8
+
+
+def test_check_in_process_verdicts(monkeypatch):
+    """The in-process check answers on the backend this process holds
+    (the suite's CPU), with the same CPU rule as the subprocess
+    probe."""
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    doc = probe.check_in_process(60.0)
+    assert doc["verdict"] == "healthy", doc
+    assert doc["platforms"] == ["cpu"] and doc["n_devices"] >= 1
+    monkeypatch.delenv("JAX_PLATFORMS")
+    doc = probe.check_in_process(60.0)
+    assert doc["verdict"] == "no-backend" and "only the CPU" in doc["error"]
+
+
+def test_check_in_process_wedge_is_bounded(monkeypatch):
+    """A runtime that never answers is a bounded `wedged` verdict: the
+    watchdog thread takes the hang, not the caller."""
+    import threading
+    import time
+
+    import jax
+
+    release = threading.Event()
+    monkeypatch.setattr(jax, "devices",
+                        lambda *a, **k: release.wait(30))
+    t0 = time.monotonic()
+    try:
+        doc = probe.check_in_process(0.2)
+    finally:
+        release.set()
+    assert doc["verdict"] == "wedged", doc
+    assert time.monotonic() - t0 < 5.0
+
+
+def test_breaker_and_watch_checks_start_no_child(monkeypatch):
+    """The breaker's half-open probe and the probe watch run in this
+    process: a chip belongs to one process at a time, so a child could
+    never open the device this process holds."""
+    import subprocess
+
+    from jepsen_tpu.resilience import breaker
+
+    def no_child(*a, **k):
+        raise AssertionError("started a child process")
+
+    monkeypatch.setattr(subprocess, "run", no_child)
+    monkeypatch.setattr(subprocess, "Popen", no_child)
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert breaker._default_probe() is True
+    assert probe.ProbeWatch(interval=60.0).tick()["verdict"] == "healthy"

@@ -166,9 +166,12 @@ def test_supervisor_flaky_budget_exhausted(monkeypatch):
 
 
 def test_supervisor_real_persistent_error_degrades(monkeypatch):
-    """The dying-chip mode: a REAL exception that survives the retry
-    budget reaches engine.analysis as DeviceUnavailable and degrades
-    to the host path — verdict preserved (docs/resilience.md)."""
+    """The dying-chip mode: an error raised while the program RUNS
+    (jax's JaxRuntimeError) that survives the retry budget reaches
+    engine.analysis as DeviceUnavailable and degrades to the host path
+    — verdict preserved (docs/resilience.md)."""
+    import jax
+
     from jepsen_tpu.models import CASRegister
     from jepsen_tpu.parallel import bitdense, engine
     m = CASRegister()
@@ -176,7 +179,7 @@ def test_supervisor_real_persistent_error_degrades(monkeypatch):
     clean = engine.analysis(m, h)
 
     def chip_died(*a, **k):
-        raise RuntimeError("XlaRuntimeError: chip fell off the bus")
+        raise jax.errors.JaxRuntimeError("INTERNAL: chip fell off the bus")
 
     # watchdog env activates the supervision slow path with no faults
     monkeypatch.setenv("JEPSEN_TPU_WATCHDOG", "30")
@@ -185,6 +188,56 @@ def test_supervisor_real_persistent_error_degrades(monkeypatch):
     assert r["valid?"] == clean["valid?"]
     assert r["resilience"]["degraded"] == "host-wgl"
     assert "chip fell off the bus" in r["resilience"]["reason"]
+
+
+def test_supervisor_lowering_error_propagates_with_watchdog(monkeypatch):
+    """A tracing/lowering error (here the compiler's refusal to
+    partition a Mosaic kernel) raised inside a supervised dispatch with
+    the watchdog armed is raised unchanged: not retried, not counted on
+    the breaker, and not degraded to the host by engine.analysis."""
+    from jepsen_tpu.models import CASRegister
+    from jepsen_tpu.parallel import bitdense, engine
+
+    def refused(*a, **k):
+        raise NotImplementedError("Mosaic kernels cannot be "
+                                  "automatically partitioned")
+
+    monkeypatch.setenv("JEPSEN_TPU_WATCHDOG", "30")
+    monkeypatch.setenv("JEPSEN_TPU_DISPATCH_RETRIES", "3")
+    r0 = _cval("resilience.retries")
+    with pytest.raises(NotImplementedError, match="Mosaic"):
+        sup.dispatch("dispatch", lambda: refused(), backend="fake-l")
+    assert _cval("resilience.retries") == r0
+    assert breaker_mod.breaker_for("fake-l").snapshot()["failures"] == 0
+    # through the engine: the check fails loudly, no host-wgl answer
+    monkeypatch.setattr(bitdense, "_check_bitdense", refused)
+    h = rand_register_history(n_ops=24, n_processes=3, seed=31)
+    with pytest.raises(NotImplementedError, match="Mosaic"):
+        engine.analysis(CASRegister(), h)
+
+
+def test_supervisor_compile_error_propagates(monkeypatch):
+    """XLA reports a failed compile with the same JaxRuntimeError type
+    as a failed execution; the supervisor tells them apart and raises
+    the compile error unchanged (a real one: a custom call with no
+    handler on this backend)."""
+    import jax
+    import numpy as np
+
+    def needs_missing_handler(x):
+        return jax.ffi.ffi_call(
+            "jepsen_no_such_target",
+            jax.ShapeDtypeStruct(x.shape, x.dtype))(x)
+
+    monkeypatch.setenv("JEPSEN_TPU_WATCHDOG", "30")
+    r0 = _cval("resilience.retries")
+    with pytest.raises(jax.errors.JaxRuntimeError, match="FFI handler"):
+        sup.dispatch("dispatch",
+                     lambda: jax.jit(needs_missing_handler)(
+                         np.ones(3, np.float32)),
+                     backend="fake-c")
+    assert _cval("resilience.retries") == r0
+    assert breaker_mod.breaker_for("fake-c").snapshot()["failures"] == 0
 
 
 def test_supervisor_crash_not_retried(monkeypatch):
@@ -347,13 +400,15 @@ def test_retries_env_alone_activates_supervision(monkeypatch):
     """An operator who sets ONLY JEPSEN_TPU_DISPATCH_RETRIES gets
     retries (and breaker bookkeeping) — not a silent fast-path
     bypass."""
+    import jax
+
     monkeypatch.setenv("JEPSEN_TPU_DISPATCH_RETRIES", "2")
     calls = []
 
     def flaky_real():
         calls.append(1)
         if len(calls) < 3:
-            raise RuntimeError("transient XlaRuntimeError")
+            raise jax.errors.JaxRuntimeError("UNAVAILABLE: transient")
         return 9
 
     assert sup.dispatch("dispatch", flaky_real, backend="fake-r") == 9
@@ -610,12 +665,10 @@ def test_mid_search_kill_resumes_from_checkpoint(monkeypatch):
     assert ei.value.checkpoint.event_index == mid.event_index
 
 
-def test_pallas_mesh_fallback_survives_supervision(monkeypatch):
-    """With the watchdog configured, a real pallas lowering gap on a
-    multi-device mesh must STILL take the cheap XLA-closure fallback
-    (bitdense._fallback_or_raise unwraps the supervisor's
-    DeviceUnavailable) — not silently degrade the bucket to the
-    100-300x host path just because supervision was active."""
+def test_pallas_mesh_error_survives_supervision(monkeypatch):
+    """With the watchdog configured, a kernel error on a multi-device
+    mesh is still raised as itself: supervision neither wraps it in
+    DeviceUnavailable nor lets a caller degrade it."""
     import jax
     import numpy as np
     from jax.sharding import Mesh
@@ -628,26 +681,15 @@ def test_pallas_mesh_fallback_survives_supervision(monkeypatch):
           for s in range(4)]
     encs = [enc_mod.encode(CASRegister(), h) for h in hs]
     mesh = Mesh(np.array(jax.devices()[:4]), ("keys",))
-    baseline = bitdense.check_batch_bitdense(encs, mesh=mesh,
-                                             use_pallas=False)
-    real = bitdense._check_bitdense_batch
 
-    def failing_on_pallas(*args, **kw):
-        if args[6]:  # use_pallas
-            raise RuntimeError("Mosaic lowering gap (simulated)")
-        return real(*args, **kw)
+    def refused(*a, **k):
+        raise NotImplementedError("Mosaic kernels cannot be "
+                                  "automatically partitioned")
 
-    monkeypatch.setattr(bitdense, "_check_bitdense_batch",
-                        failing_on_pallas)
-    monkeypatch.delenv("JEPSEN_TPU_PALLAS", raising=False)
-    monkeypatch.setattr(bitdense, "_resolve_use_pallas",
-                        lambda up, S, C, platform: (True, True))
+    monkeypatch.setattr(bitdense, "_sharded_batch_fn", refused)
     monkeypatch.setenv("JEPSEN_TPU_WATCHDOG", "30")   # supervision ON
-    rs = bitdense.check_batch_bitdense(encs, mesh=mesh)
-    assert [r["valid?"] for r in rs] == [r["valid?"] for r in baseline]
-    for r in rs:
-        assert r["closure"] == "xla-while"
-        assert "pallas closure failed" in r["closure-note"]
+    with pytest.raises(NotImplementedError, match="Mosaic"):
+        bitdense.check_batch_bitdense(encs, mesh=mesh)
 
 
 def test_breaker_stops_redispatch_across_checks(monkeypatch):

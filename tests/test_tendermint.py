@@ -902,8 +902,7 @@ def test_real_tendermint_binary_deploy_network_gated(tmp_path):
     config/genesis/key writes, daemonization, RPC readiness (the
     binary's era may ignore our [rpc] table, so candidate default
     ports are polled too), liveness, teardown. Skips with the probe
-    evidence on zero-egress hosts — every probe this round resolved
-    neither github.com nor s3 (PROBES_r05.log)."""
+    evidence on zero-egress hosts."""
     import socket
     import time as _time
 

@@ -1,7 +1,7 @@
 """Microbench for the host encode fast path (the pipeline's stage 1).
 
 The batched checker's end-to-end constant is the Python encode loop
-(PERF_R05: device-only 9,189.9 ops/s vs 8,558.4 e2e — the gap is
+(round 5 on chip: device-only 9,189.9 ops/s vs 8,558.4 e2e — the gap is
 encode + transfer, not search). PR 2 attacks it three ways; this tool
 measures each in isolation with no device runtime — encode is pure
 numpy (jax gets imported transitively but no backend is ever
