@@ -29,6 +29,7 @@ from __future__ import annotations
 from typing import Optional
 
 from jepsen_tpu import models as model_ns
+from jepsen_tpu import obs
 from jepsen_tpu.checker.core import Checker
 from jepsen_tpu.history import History, Intern
 
@@ -46,6 +47,12 @@ class Linearizable(Checker):
         self.algorithm = algorithm
 
     def check(self, test, history, opts=None):
+        # the root span of a single-key check (under independent.key on
+        # the per-key host path)
+        with obs.span("linearizable.check"):
+            return self._check(test, history, opts)
+
+    def _check(self, test, history, opts):
         model = self.model or (test or {}).get("model")
         if model is None:
             raise ValueError("The linearizable checker requires a model")

@@ -351,10 +351,10 @@ def env_path(name: str, what: str = "path") -> Optional[str]:
 #                            off (a true no-op), "1" on (artifacts land
 #                            in the store run dir / bench trace dir),
 #                            <path> on + Chrome trace JSON written there
-#   JEPSEN_TPU_JAX_PROFILE   env_path    obs — wrap device dispatch in
-#                            jax.profiler.trace(<dir>) with
-#                            TraceAnnotation-named steps so host spans
-#                            line up with the TPU timeline in Perfetto
+#   JEPSEN_TPU_JAX_PROFILE   env_path    obs — wrap a batched check in
+#                            jax.profiler.trace(<dir>) (no Python
+#                            tracer) and arm span tracing: each span
+#                            is an annotation on the capture's clock
 #   JEPSEN_TPU_SERVE_QUEUE   env_int     serve.service — per-key
 #                            pending-delta queue bound (default 64,
 #                            min 1); a full queue BLOCKS the producer

@@ -320,20 +320,26 @@ class IndependentChecker(Checker):
 
     def check(self, test, history, opts=None):
         opts = opts or {}
-        # Histories reloaded from EDN/JSONL (the `analyze` path) carry
-        # [k v] values as plain lists; by contract every keyed op under
-        # an independent checker is a KV, so if none survived
-        # serialization, re-wrap — otherwise split_history finds zero
-        # keys and the check is vacuously valid.
-        if not any(isinstance(o.get("value"), KV) for o in history):
-            history = kv_history(history)
-        subs = split_history(history)
-        ks = list(subs)
-        obs.counter("independent.keys").inc(len(ks))
+        with obs.span("independent.check") as root:
+            with obs.span("independent.split"):
+                # Histories reloaded from EDN/JSONL (the `analyze`
+                # path) carry [k v] values as plain lists; by contract
+                # every keyed op under an independent checker is a KV,
+                # so if none survived serialization, re-wrap —
+                # otherwise split_history finds zero keys and the check
+                # is vacuously valid.
+                if not any(isinstance(o.get("value"), KV)
+                           for o in history):
+                    history = kv_history(history)
+                subs = split_history(history)
+            ks = list(subs)
+            root.set(keys=len(ks))
+            obs.counter("independent.keys").inc(len(ks))
 
-        with obs.span("independent.check", keys=len(ks)):
-            results, fallback = self._batched_device_results(test, subs)
-            if results is None:
+            rs, fallback = self._batched_device_results(test, subs)
+            if rs is not None:
+                pairs = zip(ks, ({**r, "analyzer": "jax"} for r in rs))
+            else:
                 # per-key host checks run on bounded_pmap threads:
                 # propagate the span context so each key's span nests
                 # under independent.check
@@ -362,17 +368,21 @@ class IndependentChecker(Checker):
                              "history-key": k}))
 
                 pairs = bounded_pmap(wrap(check_key), ks)
-                results = dict(pairs)
 
-        self._persist(test, opts, subs, results)
-        # only proven-invalid keys; "unknown" (e.g. a crashed per-key
-        # checker) is not a failure (independent.clj:305-311)
-        failures = [k for k, r in results.items() if r.get("valid?") is False]
-        out = {
-            "valid?": merge_valid(r.get("valid?") for r in results.values()),
-            "results": results,
-            "failures": failures,
-        }
+            with obs.span("independent.assemble"):
+                results = dict(pairs)
+                self._persist(test, opts, subs, results)
+                # only proven-invalid keys; "unknown" (e.g. a crashed
+                # per-key checker) is not a failure
+                # (independent.clj:305-311)
+                failures = [k for k, r in results.items()
+                            if r.get("valid?") is False]
+                out = {
+                    "valid?": merge_valid(r.get("valid?")
+                                          for r in results.values()),
+                    "results": results,
+                    "failures": failures,
+                }
         if fallback is not None:
             # the reason stays a plain string under the historical key
             # (operators and tests grep it); the structured form —
@@ -383,9 +393,10 @@ class IndependentChecker(Checker):
 
     # -- device batch fast path
     def _batched_device_results(self, test, subs):
-        """(results, fallback): results is None when the host per-key
-        path should run. A None fallback means the device path was
-        simply not applicable (non-device checker, unpackable model);
+        """(results, fallback): results, one per key of `subs` in its
+        order, is None when the host per-key path should run. A None
+        fallback means the device path was simply not applicable
+        (non-device checker, unpackable model);
         otherwise it is a structured dict — {"reason", "class",
         "backend", "no-redispatch"} — saying the device path was
         attempted and FAILED (or was breaker-refused without an
@@ -442,7 +453,7 @@ class IndependentChecker(Checker):
                                         search_stats=self.search_stats,
                                         steal=self.steal,
                                         reshard=self.reshard)
-            return {k: {**r, "analyzer": "jax"} for k, r in zip(ks, rs)}, None
+            return rs, None
         except EncodeError as err:
             # legitimately not device-encodable (a gset key past the
             # 31-element budget, a > 64-slot crash pile-up): the host

@@ -17,8 +17,8 @@ docs/observability.md is the operator guide):
   export    Chrome trace-event JSON (opens in Perfetto, one track per
             host thread + one per device bucket), JSONL into the store
             run dir, an end-of-run summary table, the
-            ``JEPSEN_TPU_JAX_PROFILE`` bridge that lines host spans up
-            with ``jax.profiler`` TPU captures, and the flight
+            ``JEPSEN_TPU_JAX_PROFILE`` capture (the spans are its
+            annotations, on the device ops' clock), and the flight
             recorder's crash dump (``flight_dump``).
   httpd     the live ops surface (import ``jepsen_tpu.obs.httpd``
             explicitly): ``/metrics`` Prometheus text + ``/healthz`` +
@@ -55,6 +55,6 @@ from jepsen_tpu.obs.metrics import (  # noqa: F401
 )
 from jepsen_tpu.obs.tracer import (  # noqa: F401
     Span, Tracer, configure, counter_sample, ctx_runner, current_span,
-    device_annotation, enabled, flight_active, jax_profile_dir,
-    maybe_jax_profile, reset, span, timer, tracer,
+    enabled, flight_active, jax_profile_dir, maybe_jax_profile, reset,
+    span, timer, tracer,
 )

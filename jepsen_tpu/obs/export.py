@@ -11,7 +11,9 @@ metadata events naming processes and threads:
     pipeline's stage spans;
   * pid 2 ("device"): one track per device bucket (synthetic spans
     recorded via ``Tracer.add_span(track=...)`` for each chunk's
-    dispatch->finalize window — no host thread "runs" these).
+    dispatch->finalize window — no host thread "runs" these);
+    the collector's ``python.gc`` spans take their own track ``gc``
+    under the host process.
 
 Open the file in Perfetto next to a ``jax.profiler`` capture of the
 same run (``JEPSEN_TPU_JAX_PROFILE``) and the host spans line up with
@@ -70,13 +72,15 @@ def chrome_trace(tr: Optional[_tracer.Tracer] = None,
     seen_threads: Dict[int, str] = {}
     for s in spans:
         if s.track is not None:
+            pid = (HOST_PID if s.track == _tracer.GC_TRACK
+                   else DEVICE_PID)
             if s.track not in track_tid:
                 tid = len(track_tid) + 1
                 track_tid[s.track] = tid
-                events.append({"ph": "M", "pid": DEVICE_PID, "tid": tid,
+                events.append({"ph": "M", "pid": pid, "tid": tid,
                                "name": "thread_name",
                                "args": {"name": s.track}})
-            pid, tid = DEVICE_PID, track_tid[s.track]
+            tid = track_tid[s.track]
         else:
             tid, tname = s.thread
             if tid not in seen_threads:
@@ -172,7 +176,7 @@ def summary(tr: Optional[_tracer.Tracer] = None,
             a = agg.setdefault(s.name, [0, 0.0, 0.0])
             a[0] += 1
             a[1] += s.wall
-            a[2] += s.cpu
+            a[2] += s.cpu or 0.0
         if agg:
             lines.append(f"{'span':<28} {'count':>7} {'total_s':>10} "
                          f"{'mean_ms':>10} {'cpu_s':>9}")

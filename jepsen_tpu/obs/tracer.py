@@ -26,17 +26,30 @@ zero retained allocations. Flag changes after import are picked up via
 :func:`reset` (tests) — a real run sets the env before the process
 starts.
 
+Clocks: with full tracing on, every span also opens a
+``jax.profiler.TraceAnnotation`` of its name and attributes (once jax
+is imported; the import stays lazy), so a profiler capture shows the
+program's spans on its host plane, on the same clock as the device's
+ops. Each collector pause becomes a ``python.gc`` span on its own
+track ``gc`` (a ``gc.callbacks`` hook, installed while a full tracer
+is active and removed on ``configure(on=False)``/``reset()``).
+
 Thread-safety: finished spans append to one lock-protected list; the
-contextvar handles per-thread currency. ``process_time()`` is
-process-wide, so a span's ``cpu`` reads "CPU seconds the process spent
-while this span was open" — comparable across spans only when the
-machine isn't oversubscribed, which is exactly how the bench uses it.
+contextvar handles per-thread currency. Only :func:`timer` spans read
+``process_time()`` (process-wide: ``cpu`` is "CPU seconds the process
+spent while this span was open", comparable across spans only when the
+machine isn't oversubscribed): a process-CPU clock read is a system
+call, about 5.5 us on a TPU v5e host against 0.09 us for
+``perf_counter``, so the hot-path :func:`span` records wall time alone
+and leaves ``cpu`` None.
 """
 
 from __future__ import annotations
 
 import contextvars
+import gc
 import itertools
+import sys
 import threading
 from collections import deque
 from time import perf_counter, process_time
@@ -47,6 +60,9 @@ from jepsen_tpu import envflags
 
 _current: contextvars.ContextVar = contextvars.ContextVar(
     "jepsen_tpu_obs_span", default=None)
+
+# the track of the collector's python.gc spans
+GC_TRACK = "gc"
 
 # default flight-recorder ring capacity (closed spans) when
 # JEPSEN_TPU_FLIGHT_RECORDER=1; N>=2 sets the capacity explicitly
@@ -87,6 +103,10 @@ class Tracer:
         # (pipeline.inflight, breaker state, serve queue depth) and the
         # search-stats trajectories line up with the span tracks
         self._counters: List[tuple] = []
+        # python.gc spans, appended by the collector hook WITHOUT the
+        # lock: a collection can start between any two bytecodes,
+        # including inside this tracer's own locked sections
+        self._gc_spans: List["Span"] = []
         self._ring: Optional[deque] = (deque(maxlen=ring)
                                        if ring else None)
         self.flight_baseline: Optional[dict] = None
@@ -118,7 +138,8 @@ class Tracer:
 
     def spans(self) -> List["Span"]:
         with self._lock:
-            return list(self._spans)
+            out = list(self._spans)
+        return out + self._gc_spans
 
     def record_counter(self, name: str, t: float, value) -> None:
         """Record one counter-track sample. Flight-only recorders skip
@@ -143,7 +164,25 @@ class Tracer:
             out = self._spans
             self._spans = []
             self._counters = []
-            return out
+        gcs, self._gc_spans = self._gc_spans, []
+        return out + gcs
+
+    def gc_hook(self) -> Callable:
+        """The ``gc.callbacks`` hook that records each collector pause
+        as a ``python.gc`` span on track ``gc``, with no parent: a
+        collection inside a span is not taken out of its self time."""
+        started = [0.0]
+
+        def hook(phase, info):
+            if phase == "start":
+                started[0] = perf_counter()
+                return
+            s = Span(None, "python.gc", {"generation": info["generation"]})
+            s.sid = self.next_id()
+            s.t0, s.t1 = started[0], perf_counter()
+            s.track = GC_TRACK
+            self._gc_spans.append(s)
+        return hook
 
     def add_span(self, name: str, t0: float, t1: float,
                  track: Optional[str] = None, parent: Optional[int] = None,
@@ -165,43 +204,60 @@ class Tracer:
 
 class Span:
     """One open (then finished) span. Context-manager protocol; also
-    usable pre-populated via Tracer.add_span. ``wall``/``cpu`` are
-    valid after ``__exit__`` — :func:`timer` exploits that to make the
-    recorded span and the caller's measured number one and the same
-    clock read."""
+    usable pre-populated via Tracer.add_span. ``wall`` (and ``cpu``,
+    for a `timed` span) are valid after ``__exit__`` — :func:`timer`
+    exploits that to make the recorded span and the caller's measured
+    number one and the same clock read."""
 
     __slots__ = ("tracer", "name", "args", "sid", "parent", "t0", "t1",
-                 "cpu", "_cpu0", "_tok", "thread", "track")
+                 "cpu", "_cpu0", "_tok", "_ann", "thread", "track")
 
-    def __init__(self, tracer: Optional[Tracer], name: str, args: dict):
+    def __init__(self, tracer: Optional[Tracer], name: str, args: dict,
+                 timed: bool = False):
         self.tracer = tracer
         self.name = name
         self.args = args
         self.sid = 0
         self.parent = None
         self.t0 = self.t1 = 0.0
-        self.cpu = 0.0
+        # process CPU seconds, measured for timed spans only
+        self.cpu = 0.0 if timed else None
+        self._cpu0 = None
         self.thread = None
         self.track = None
+        self._ann = None
 
     def __enter__(self) -> "Span":
         tr = self.tracer
+        ann = None
         if tr is not None:
             self.sid = tr.next_id()
             par = _current.get()
             self.parent = par.sid if par is not None else None
             self._tok = _current.set(self)
+            if not tr.flight_only:
+                ann = _annotation_cls()
         else:
             self._tok = None
         t = threading.current_thread()
         self.thread = (t.ident, t.name)
-        self._cpu0 = process_time()
+        if self.cpu is not None:
+            self._cpu0 = process_time()
+        if ann is not None and ann.is_enabled():
+            # only while a profiler session records; opened right
+            # before the clock read, so the profiler's start and t0 are
+            # the same moment on two clocks
+            self._ann = ann(self.name, **self.args)
+            self._ann.__enter__()
         self.t0 = perf_counter()
         return self
 
     def __exit__(self, *exc) -> bool:
         self.t1 = perf_counter()
-        self.cpu = process_time() - self._cpu0
+        if self._cpu0 is not None:
+            self.cpu = process_time() - self._cpu0
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         if self._tok is not None:
             _current.reset(self._tok)
         if self.tracer is not None:
@@ -220,8 +276,9 @@ class Span:
     def to_dict(self) -> dict:
         d = {"type": "span", "name": self.name, "id": self.sid,
              "parent": self.parent,
-             "t0": round(self.t0, 6), "wall": round(self.wall, 6),
-             "cpu": round(self.cpu, 6)}
+             "t0": round(self.t0, 6), "wall": round(self.wall, 6)}
+        if self.cpu is not None:
+            d["cpu"] = round(self.cpu, 6)
         if self.thread is not None:
             d["thread"] = self.thread[1]
             d["tid"] = self.thread[0]
@@ -260,6 +317,36 @@ _NOOP = _NoopSpan()
 _UNSET = object()
 _state = _UNSET
 _state_lock = threading.Lock()
+_gc_hook: Optional[Callable] = None   # the installed collector hook
+_annotation = None   # jax.profiler.TraceAnnotation, once jax is imported
+
+
+def _annotation_cls():
+    """``jax.profiler.TraceAnnotation``, or None while jax is not
+    imported: obs never imports jax itself, and with no jax there is no
+    profiler session for an annotation to land in."""
+    global _annotation
+    if _annotation is None and "jax" in sys.modules:
+        from jax.profiler import TraceAnnotation
+        _annotation = TraceAnnotation
+    return _annotation
+
+
+def _set_state(st) -> None:
+    """Make `st` the session (caller holds _state_lock) and move the
+    collector hook to it: installed for a full tracer, removed
+    otherwise, so tracing off costs the collector nothing."""
+    global _state, _gc_hook
+    if _gc_hook is not None:
+        try:
+            gc.callbacks.remove(_gc_hook)
+        except ValueError:
+            pass
+        _gc_hook = None
+    _state = st
+    if isinstance(st, Tracer) and not st.flight_only:
+        _gc_hook = st.gc_hook()
+        gc.callbacks.append(_gc_hook)
 
 
 def _flight_capacity() -> int:
@@ -273,20 +360,23 @@ def _flight_capacity() -> int:
 
 
 def _resolve():
-    global _state
     with _state_lock:
         if _state is _UNSET:
             path = envflags.env_path("JEPSEN_TPU_TRACE",
                                      what="trace output path")
+            if path is None and jax_profile_dir() is not None:
+                # a profiler capture keeps its named steps: the
+                # annotations are the spans
+                path = ""
             ring = _flight_capacity()
             if path is not None:
-                _state = Tracer(path, ring=ring or None)
+                _set_state(Tracer(path, ring=ring or None))
             elif ring:
                 # flight recorder alone: spans land in the bounded
                 # ring only, invisible to enabled()/export_run
-                _state = Tracer("", ring=ring, flight_only=True)
+                _set_state(Tracer("", ring=ring, flight_only=True))
             else:
-                _state = None
+                _set_state(None)
     return _state
 
 
@@ -337,11 +427,12 @@ def timer(name: str, **args) -> Span:
     it is on the recorded span is the SAME clock reads — the mechanism
     by which bench split lines and trace spans can never disagree
     (one measurement site). Not for hot paths: it allocates a Span per
-    call even when disabled; use :func:`span` there."""
+    call even when disabled, and reads the process CPU clock; use
+    :func:`span` there."""
     st = _state
     if st is _UNSET:
         st = _resolve()
-    return Span(st, name, args)
+    return Span(st, name, args, timed=True)
 
 
 def counter_sample(name: str, value, t: Optional[float] = None) -> None:
@@ -368,19 +459,17 @@ def configure(on: bool = True, path: str = "",
     regardless of the env flag. Returns the new tracer (or None).
     ``ring``/``flight_only`` arm the flight recorder the way the
     JEPSEN_TPU_FLIGHT_RECORDER flag would."""
-    global _state
     with _state_lock:
-        _state = (Tracer(path, ring=ring, flight_only=flight_only)
-                  if on else None)
+        _set_state(Tracer(path, ring=ring, flight_only=flight_only)
+                   if on else None)
     return _state
 
 
 def reset():
     """Drop the session and re-resolve from the env on next use —
     how tests flip JEPSEN_TPU_TRACE mid-process."""
-    global _state
     with _state_lock:
-        _state = _UNSET
+        _set_state(_UNSET)
 
 
 def current_span() -> Optional[Span]:
@@ -446,25 +535,17 @@ class _MaybeCtx:
 def maybe_jax_profile() -> _MaybeCtx:
     """jax.profiler.trace(dir) when JEPSEN_TPU_JAX_PROFILE is set, else
     a no-op — wraps a whole batched check so the TPU capture and the
-    host spans share a session."""
+    host spans share a session. The session runs without the Python
+    function tracer (``python_tracer_level=0``), which would slow the
+    host several times over: the spans' annotations name the host
+    steps instead."""
     d = jax_profile_dir()
     if d is None:
         return _MaybeCtx(None)
 
     def make():
         import jax
-        return jax.profiler.trace(d)
-    return _MaybeCtx(make)
-
-
-def device_annotation(name: str) -> _MaybeCtx:
-    """jax.profiler.TraceAnnotation(name) when profiling is on, else a
-    no-op — names the dispatch step in the TPU timeline so host spans
-    line up with device work in Perfetto."""
-    if jax_profile_dir() is None:
-        return _MaybeCtx(None)
-
-    def make():
-        import jax
-        return jax.profiler.TraceAnnotation(name)
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        return jax.profiler.trace(d, profiler_options=opts)
     return _MaybeCtx(make)

@@ -519,7 +519,8 @@ def check_encoded_bitdense(e: EncodedHistory,
         use_pallas, S, C, platform)
     closure_mode = _resolve_closure_mode(closure_mode, use_pallas)
     ss = eng_mod._resolve_search_stats(search_stats)
-    xs = _xs_dense(e, C)
+    with obs.span("transfer.place", keys=1):
+        xs = _xs_dense(e, C)
     if timings is not None:
         t0 = perf_counter()
         xs = {k: jnp.asarray(v) for k, v in xs.items()}
@@ -533,40 +534,45 @@ def check_encoded_bitdense(e: EncodedHistory,
     programs.track("bitdense.check", xs,
                    (e.step_name, S, C, e.state_lo, use_pallas,
                     interpret, closure_mode, ss))
-    with obs.span("bitdense.check", S=S, C=C), \
-            obs.device_annotation(f"bitdense single S{S} C{C}"):
+    with obs.span("bitdense.check", S=S, C=C):
         def _search():
-            out = _check_bitdense(xs, jnp.int32(e.state0),
-                                  e.step_name, S, C,
-                                  e.state_lo, use_pallas,
-                                  interpret, closure_mode, ss)
-            # bool() materializes: async failures/hangs surface inside
-            # the supervised window (the device wait ends here)
-            if ss:
-                valid, fail_r, ys = out
-                return bool(valid), fail_r, jax.tree.map(np.asarray, ys)
-            valid, fail_r = out
-            return bool(valid), fail_r
+            with obs.span("bitdense.issue", K=1, S=S, C=C):
+                out = _check_bitdense(xs, jnp.int32(e.state0),
+                                      e.step_name, S, C,
+                                      e.state_lo, use_pallas,
+                                      interpret, closure_mode, ss)
+            # the device wait ends here, inside the supervised window
+            # (async failures and hangs surface in it); what follows is
+            # the copy of the results to the host
+            jax.block_until_ready(out)
+            with obs.span("bitdense.fetch"):
+                if ss:
+                    valid, fail_r, ys = out
+                    return (bool(valid), int(fail_r),
+                            jax.tree.map(np.asarray, ys))
+                valid, fail_r = out
+                return bool(valid), int(fail_r)
 
         res = sup.dispatch("dispatch", _search, backend=platform)
         valid_b, fail_r = res[0], res[1]
     if timings is not None:
         timings["device_secs"] = perf_counter() - t0
-    out = {"valid?": valid_b, "engine": "bitdense",
-           "states": S, "slots": C,
-           # the dense reachable-set tensor IS a complete visited set —
-           # the sparse sort/hash strategies (JEPSEN_TPU_DEDUPE) have
-           # nothing to select here; the tag keeps result schemas
-           # uniform across engines
-           "dedupe": "dense",
-           "closure": "pallas" if use_pallas
-           else f"xla-{closure_mode}"}
-    if ss:
-        out["stats"] = eng_mod.finish_stats_block(
-            _stats_block_bitdense(res[2], S, C), ts0, perf_counter())
-    if not out["valid?"]:
-        from jepsen_tpu.parallel.encode import fail_op_fields
-        out.update(fail_op_fields(e, int(fail_r)))
+    with obs.span("bitdense.results"):
+        out = {"valid?": valid_b, "engine": "bitdense",
+               "states": S, "slots": C,
+               # the dense reachable-set tensor IS a complete visited
+               # set — the sparse sort/hash strategies
+               # (JEPSEN_TPU_DEDUPE) have nothing to select here; the
+               # tag keeps result schemas uniform across engines
+               "dedupe": "dense",
+               "closure": "pallas" if use_pallas
+               else f"xla-{closure_mode}"}
+        if ss:
+            out["stats"] = eng_mod.finish_stats_block(
+                _stats_block_bitdense(res[2], S, C), ts0, perf_counter())
+        if not out["valid?"]:
+            from jepsen_tpu.parallel.encode import fail_op_fields
+            out.update(fail_op_fields(e, fail_r))
     return out
 
 
@@ -681,9 +687,6 @@ class PendingBitdenseBatch:
         self._issue()
 
     def _issue(self):
-        # the annotation names this dispatch in a jax.profiler TPU
-        # capture (JEPSEN_TPU_JAX_PROFILE) so the device timeline
-        # row lines up with the host's bitdense.dispatch span
         from time import perf_counter
         self._t_issue = perf_counter()
         # population tracking only — the batch closure program is not
@@ -698,8 +701,8 @@ class PendingBitdenseBatch:
         fn = (functools.partial(_check_bitdense_batch, **statics)
               if self.mesh is None
               else _sharded_batch_fn(self.mesh, len(self.encs), **statics))
-        with obs.device_annotation(
-                f"bitdense K{len(self.encs)} S{self.S} C{self.C}"):
+        with obs.span("bitdense.issue", K=len(self.encs), S=self.S,
+                      C=self.C):
             # supervised (resilience.supervisor): faults inject here,
             # the breaker records the outcome; the program is ISSUED
             # inside the window, the async wait is finalize()'s own
@@ -721,12 +724,23 @@ class PendingBitdenseBatch:
             # materialize inside a supervised window: this wait is
             # where a wedged runtime actually hangs, and async dispatch
             # surfaces runtime failures here, not at the issue
-            valid, fail_r = sup.dispatch(
-                "dispatch",
-                lambda: (np.asarray(self._valid),
-                         np.asarray(self._fail_r)),
-                backend=self.platform)
+            valid, fail_r = sup.dispatch("dispatch", self._fetch,
+                                         backend=self.platform)
         self.device_wait_secs = tm.wall
+        with obs.span("bitdense.results", keys=len(self.encs)):
+            self._results = self._build(valid, fail_r)
+        return self._results
+
+    def _fetch(self):
+        # the wait ends at block_until_ready; the span is the copy of
+        # the results to the host that follows
+        jax.block_until_ready((self._valid, self._fail_r))
+        with obs.span("bitdense.fetch"):
+            return np.asarray(self._valid), np.asarray(self._fail_r)
+
+    def _build(self, valid, fail_r) -> list:
+        """The per-key result dicts, their stats blocks and the ledger
+        record, from the fetched verdicts."""
         closure = "pallas" if self.up else f"xla-{self.mode}"
         ys = None
         if self.search_stats and self._ys is not None:
@@ -774,7 +788,6 @@ class PendingBitdenseBatch:
                 outcome={"valid": n_valid,
                          "invalid": len(out) - n_valid,
                          "overflow": 0})
-        self._results = out
         return out
 
 

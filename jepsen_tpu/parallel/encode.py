@@ -31,6 +31,7 @@ from typing import Optional
 import numpy as np
 
 from jepsen_tpu import models as model_ns
+from jepsen_tpu import obs
 from jepsen_tpu.history import (
     History, Intern, calls as history_calls, prune_wildcard_calls,
 )
@@ -348,9 +349,10 @@ def pad_batch(encs: list, mesh=None, min_slots: int = 1,
     xs["ev_slot"] = ev
     state0 = np.array([e.state0 for e in encs], np.int32)
 
-    if mesh is not None:
-        xs, state0 = place_batch(xs, state0, mesh)
-    else:
-        xs = {k: jnp.asarray(v) for k, v in xs.items()}
-        state0 = jnp.asarray(state0)
+    with obs.span("transfer.place", keys=K):
+        if mesh is not None:
+            xs, state0 = place_batch(xs, state0, mesh)
+        else:
+            xs = {k: jnp.asarray(v) for k, v in xs.items()}
+            state0 = jnp.asarray(state0)
     return xs, state0, S, C, R

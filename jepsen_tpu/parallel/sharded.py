@@ -1301,8 +1301,8 @@ def check_encoded_sharded(e: EncodedHistory, mesh: Mesh,
             # one span per capacity-tier attempt, per-device capacity
             # attached — the escalation ladder renders as widening
             # steps in the trace
-            with obs.span("sharded.tier", capacity=N, per_device=Nd), \
-                    obs.device_annotation(f"sharded N{N} D{n_dev}"):
+            with obs.span("sharded.tier", capacity=N, per_device=Nd,
+                          devices=n_dev):
                 def _tier(Nd=Nd, mode=mode):
                     if hier:
                         out = _check_sharded2d(xs, state0, e.step_name,

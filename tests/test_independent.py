@@ -176,3 +176,73 @@ def test_independent_checker_plain_fn():
     r = c.check({}, _keyed_register_history())
     assert r["valid?"] is True
     assert sorted(seen) == ["x", "y"]
+
+
+# ------------------------------------------------- span tree of a check
+
+
+@pytest.fixture
+def traced():
+    from jepsen_tpu import obs
+    tr = obs.configure(True)
+    yield tr
+    obs.configure(False)
+    obs.reset()
+
+
+def _tree(spans):
+    """{name: [parent name, ...]} over the host spans (the collector's
+    python.gc spans, on their own track, left out)."""
+    by_id = {s.sid: s for s in spans}
+    out = {}
+    for s in spans:
+        if s.track is None:
+            p = by_id.get(s.parent)
+            out.setdefault(s.name, []).append(p.name if p else None)
+    return out
+
+
+def test_keyed_device_check_span_tree(traced):
+    c = independent.checker(linearizable(CASRegister(), algorithm="jax"))
+    c.check({}, _keyed_register_history())
+    traced.drain()              # the first check compiles
+    r = c.check({}, _keyed_register_history())
+    assert r["failures"] == ["y"]
+    tree = _tree(traced.drain())
+    assert tree["independent.check"] == [None]
+    assert tree["independent.split"] == ["independent.check"]
+    assert tree["independent.assemble"] == ["independent.check"]
+    assert tree["independent.device_batch"] == ["independent.check"]
+    assert tree["transfer.place"] == ["bitdense.pad_place"]
+    assert tree["bitdense.fetch"] == ["bitdense.finalize"]
+    assert tree["bitdense.issue"] == ["engine.check_batch"]
+    assert tree["bitdense.results"] == ["engine.check_batch"]
+
+
+def test_host_per_key_check_roots_each_key_in_linearizable_check(traced):
+    c = independent.checker(linearizable(CASRegister(), algorithm="wgl"))
+    c.check({}, _keyed_register_history())
+    tree = _tree(traced.drain())
+    assert tree["independent.key"] == ["independent.check"] * 2
+    assert tree["linearizable.check"] == ["independent.key"] * 2
+    assert tree["independent.assemble"] == ["independent.check"]
+
+
+def test_spans_read_by_the_benchmark_keep_their_count_and_children(
+        traced):
+    """The benchmark reads engine.encode_batch by its self time and
+    bitdense.pad_place / .finalize by their total: once each per keyed
+    check, and the encode span gains no child that would take time out
+    of it."""
+    c = independent.checker(linearizable(CASRegister(), algorithm="jax"))
+    c.check({}, _keyed_register_history())
+    traced.drain()
+    for _ in range(3):
+        c.check({}, _keyed_register_history())
+    spans = traced.drain()
+    names = [s.name for s in spans]
+    for name in ("engine.encode_batch", "bitdense.pad_place",
+                 "bitdense.finalize", "independent.device_batch"):
+        assert names.count(name) == 3, name
+    encode = {s.sid for s in spans if s.name.startswith("engine.encode")}
+    assert not [s.name for s in spans if s.parent in encode]

@@ -74,6 +74,30 @@ def _families():
             (FIFOQueue(), fifo), (Mutex(), mutex)]
 
 
+# the spans that put every stretch of a check under a name
+NEW_SPANS = ("independent.split", "independent.assemble",
+             "linearizable.check", "bitdense.issue", "bitdense.fetch",
+             "bitdense.results", "transfer.place", "python.gc")
+
+
+def _keyed_and_single_checks():
+    """One keyed check through the device batch and one single-key
+    check on the device engine."""
+    from jepsen_tpu import independent
+    from jepsen_tpu.checker import linearizable
+    from jepsen_tpu.independent import KV
+
+    lin = linearizable(CASRegister(), algorithm="jax")
+    keyed = _h(invoke_op(0, "write", KV("x", 1)),
+               ok_op(0, "write", KV("x", 1)),
+               invoke_op(1, "read", KV("y", None)),
+               ok_op(1, "read", KV("y", None)))
+    independent.checker(lin).check({}, keyed)
+    return lin.check({}, _h(invoke_op(0, "write", 1), ok_op(0, "write", 1),
+                            invoke_op(1, "read", None),
+                            ok_op(1, "read", 2)))
+
+
 # ------------------------------------------------- disabled = no-op
 
 
@@ -87,7 +111,7 @@ def test_disabled_span_is_singleton_noop():
     assert s1.wall == 0.0 and s1.cpu == 0.0
 
 
-def test_disabled_span_cpu_budget_and_zero_allocations():
+def test_disabled_span_cpu_budget_and_zero_allocations(monkeypatch):
     """The hot-path guard: with tracing off, span() must cost no more
     than a few hundred ns of CPU per call and retain NOTHING inside
     the obs module. Budgeted on process_time (load-insensitive, the
@@ -122,12 +146,41 @@ def test_disabled_span_cpu_budget_and_zero_allocations():
                      before.filter_traces(flt), "filename"))
     assert growth <= 0, f"obs retained {growth} bytes over 50k no-ops"
 
+    # the span sites of a check allocate no Span with tracing off, and
+    # no collector hook is installed
+    made = []
+    real_init = trmod.Span.__init__
+
+    def spy(self, tracer, name, args, timed=False):
+        made.append(name)
+        real_init(self, tracer, name, args, timed)
+
+    monkeypatch.setattr(trmod.Span, "__init__", spy)
+    _keyed_and_single_checks()
+    import gc
+    gc.collect()
+    assert not set(made) & set(NEW_SPANS), made
+    assert trmod._gc_hook is None
+
 
 def test_timer_measures_even_when_disabled():
     with obs.timer("t", shape="x") as tm:
         sum(range(50_000))
     assert tm.wall > 0
     assert obs.tracer() is None    # nothing recorded anywhere
+
+
+def test_only_timer_spans_read_the_process_cpu_clock():
+    """A process-CPU clock read is a system call (microseconds on a
+    TPU host): the hot-path span records wall time alone."""
+    obs.configure(True)
+    with obs.span("hot") as s:
+        sum(range(50_000))
+    with obs.timer("timed") as tm:
+        sum(range(50_000))
+    assert s.cpu is None and "cpu" not in s.to_dict()
+    assert tm.cpu > 0 and tm.to_dict()["cpu"] > 0
+    assert "hot" in obs.summary()
 
 
 # ------------------------------------------------- span mechanics
@@ -186,6 +239,151 @@ def test_flag_gating_and_env_path_accessor(monkeypatch):
     assert obs.jax_profile_dir() == "store/jax_profile"
     monkeypatch.setenv("JEPSEN_TPU_JAX_PROFILE", "/tmp/prof")
     assert obs.jax_profile_dir() == "/tmp/prof"
+
+
+def test_jax_profile_flag_arms_tracing_without_the_python_tracer(
+        monkeypatch, tmp_path):
+    """JEPSEN_TPU_JAX_PROFILE alone turns span tracing on (the spans
+    are the capture's named steps), and its session runs with the
+    Python function tracer off."""
+    import jax
+
+    monkeypatch.setenv("JEPSEN_TPU_JAX_PROFILE", str(tmp_path))
+    obs.reset()
+    assert obs.enabled() and obs.tracer().path == ""
+    seen = {}
+
+    class Session:
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    def trace(d, profiler_options=None, **kw):
+        seen["dir"], seen["opts"] = d, profiler_options
+        return Session()
+
+    monkeypatch.setattr(jax.profiler, "trace", trace)
+    with obs.maybe_jax_profile():
+        pass
+    assert seen["dir"] == str(tmp_path)
+    assert seen["opts"].python_tracer_level == 0
+
+
+# ------------------------------------------------- the span tree
+
+
+def _tree(spans):
+    by_id = {s.sid: s for s in spans}
+    out = {}
+    for s in spans:
+        if s.track is None:
+            p = by_id.get(s.parent)
+            out.setdefault(s.name, []).append(p.name if p else None)
+    return out
+
+
+def test_single_key_check_span_tree():
+    _keyed_and_single_checks()          # compiles
+    tr = obs.configure(True)
+    r = _keyed_and_single_checks()
+    assert r["valid?"] is False
+    tree = _tree(tr.drain())
+    assert tree["linearizable.check"] == [None]
+    assert tree["engine.encode"] == ["linearizable.check"]
+    assert tree["bitdense.check"] == ["linearizable.check"]
+    assert tree["bitdense.results"] == ["engine.check_batch",
+                                        "linearizable.check"]
+    assert tree["bitdense.issue"] == ["engine.check_batch",
+                                      "bitdense.check"]
+    assert tree["bitdense.fetch"] == ["bitdense.finalize",
+                                      "bitdense.check"]
+    assert tree["transfer.place"] == ["bitdense.pad_place",
+                                      "linearizable.check"]
+    assert tree["independent.check"] == [None]
+    assert set(NEW_SPANS) - set(tree) == {"python.gc"}
+
+
+def test_gc_pauses_are_spans_on_their_own_track_with_no_parent():
+    import gc
+
+    tr = obs.configure(True)
+    with obs.span("outer"):
+        gc.collect()
+    gcs = [s for s in tr.spans() if s.name == "python.gc"]
+    assert gcs and all(s.parent is None and s.track == "gc"
+                       for s in gcs)
+    assert any(s.args == {"generation": 2} for s in gcs)
+    outer = [s for s in tr.spans() if s.name == "outer"][0]
+    assert outer.t0 <= gcs[-1].t0 <= gcs[-1].t1 <= outer.t1
+    events = obs.chrome_trace(tr)
+    track = [e for e in events if e["ph"] == "M"
+             and e["name"] == "thread_name" and e["args"]["name"] == "gc"]
+    assert [e["pid"] for e in track] == [1]      # under the host
+    assert [s.name for s in tr.drain()].count("python.gc") == len(gcs)
+    assert tr.spans() == []
+
+
+def test_gc_callbacks_restored_after_tracing_turns_off():
+    import gc
+
+    before = list(gc.callbacks)
+    obs.configure(True)
+    obs.configure(True)                 # a new session replaces the hook
+    assert len(gc.callbacks) == len(before) + 1
+    obs.configure(False)
+    assert gc.callbacks == before
+    obs.configure(True)
+    obs.reset()
+    assert gc.callbacks == before
+    obs.configure(True, ring=8, flight_only=True)
+    assert gc.callbacks == before       # the flight recorder alone: none
+
+
+def test_spans_are_profiler_annotations_on_the_span_clock(tmp_path):
+    """Under a profiler session with the Python tracer off, each span
+    appears by name on a /host: plane, and one offset (from an anchor
+    annotation opened beside a perf_counter read, as the benchmark's
+    `bench.window`) maps the trace's starts onto the spans' own within
+    200 us."""
+    import glob
+    from time import perf_counter, sleep
+
+    import jax
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData
+
+    tr = obs.configure(True)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with jax.profiler.TraceAnnotation("anchor"):
+            t_anchor = perf_counter()
+            for i in range(40):
+                with obs.span("demo.outer", i=i):
+                    with obs.span("demo.inner"):
+                        jnp.ones(8).block_until_ready()
+                    sleep(0.001)
+    finally:
+        jax.profiler.stop_trace()
+    spans = tr.drain()
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                            / "*.xplane.pb"))
+    starts: dict = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    starts.setdefault(ev.name, []).append(ev.start_ns)
+    a0 = starts["anchor"][0]
+    for name in ("demo.outer", "demo.inner"):
+        ours = sorted(s.t0 for s in spans if s.name == name)
+        theirs = sorted(t_anchor + (ns - a0) / 1e9 for ns in starts[name])
+        assert len(ours) == len(theirs) == 40
+        worst = max(abs(a - b) for a, b in zip(ours, theirs))
+        assert worst < 200e-6, f"{name}: {worst * 1e6:.0f} us"
 
 
 # ------------------------------------------------- metrics registry
