@@ -29,6 +29,7 @@ from __future__ import annotations
 
 import json
 from dataclasses import dataclass, field
+from itertools import compress, repeat
 from typing import Any, Callable, Iterable, Iterator, Optional
 
 import numpy as np
@@ -378,41 +379,52 @@ def calls(history: History, drop_failed: bool = True) -> list:
 
     With drop_failed (the default), failed ops are dropped — they did not
     take effect (knossos `without-failures` preprocessing); otherwise they
-    are kept with failed=True. Nemesis and non-client ops are skipped.
-    Crashed (:info) calls get complete_index = len(history).
+    are kept as completed calls. Nemesis and non-client ops (any process
+    that is not an int; client process ids fit an int64) are skipped. Crashed (:info) calls get
+    complete_index = len(history).
+
+    Pairing is columnar: the client ops are stably sorted by process,
+    and a completion pairs with the op just before it in its process's
+    group when that op is an invoke. That is the one-open-op-per-process
+    rule exactly: an invoke followed by another invoke of the same
+    process stays open (crashed), and a completion with no open invoke
+    is ignored. Each column is read once per op, and the Call records
+    are built positionally, in field order, in one pass.
     """
     n = len(history)
-    open_by_process: dict = {}
-    out: list = []
-    failed: set = set()
-    for i, o in enumerate(history):
-        p = o.get("process")
-        if not isinstance(p, int):
-            continue
-        if o.is_invoke:
-            c = Call(
-                index=-1, process=p, f=o.get("f"), value=o.get("value"),
-                result=None, invoke_index=i, complete_index=n, crashed=True,
-            )
-            open_by_process[p] = c
-            out.append(c)
-        else:
-            c = open_by_process.pop(p, None)
-            if c is None:
-                continue
-            if o.is_ok:
-                c.result = o.get("value")
-                c.complete_index = i
-                c.crashed = False
-            elif o.is_fail:
-                c.complete_index = i
-                c.crashed = False
-                failed.add(id(c))
+
+    def col(key, ops=history):
+        return map(dict.get, ops, repeat(key))
+
+    procs = list(col("process"))
+    client = np.fromiter(map(isinstance, procs, repeat(int)), bool, n)
+    pos = np.flatnonzero(client)
+    if not len(pos):
+        return []
+    types = np.fromiter(col("type"), object, n)
+    proc = np.fromiter(compress(procs, client), np.int64, len(pos))
+    by_proc = np.argsort(proc, kind="stable")
+    order, sp = pos[by_proc], proc[by_proc]  # history positions by process
+    si = types[order] == "invoke"
+    paired = np.flatnonzero((sp[1:] == sp[:-1]) & si[:-1] & ~si[1:]) + 1
+    comp = np.full(n, -1, np.int64)  # each invoke's completion, or -1
+    comp[order[paired - 1]] = order[paired]
+
+    inv = pos[types[pos] == "invoke"]
+    k = comp[inv]
+    ok = (k >= 0) & (types[k] == "ok")
+    done = ok | ((k >= 0) & (types[k] == "fail"))
     if drop_failed:
-        out = [c for c in out if id(c) not in failed]
-    for j, c in enumerate(out):
-        c.index = j
-    return out
+        keep = ~done | ok
+        inv, k, ok, done = inv[keep], k[keep], ok[keep], done[keep]
+    values = list(col("value")) + [None]  # values[n]: no ok completion
+    inv_l = inv.tolist()
+    return list(map(
+        Call, range(len(inv_l)), map(procs.__getitem__, inv_l),
+        col("f", map(history.__getitem__, inv_l)),
+        map(values.__getitem__, inv_l),
+        map(values.__getitem__, np.where(ok, k, n).tolist()),
+        inv_l, np.where(done, k, n).tolist(), (~done).tolist()))
 
 
 def prune_wildcard_calls(cs: list) -> list:

@@ -14,6 +14,12 @@ here, once, on the host:
     it: which slots are occupied and the packed op (f, a0, a1, wild) in
     each.
 
+Both stages are whole-array passes over the per-call columns: return
+rows and each call's open/close rows come from a sort and a
+searchsorted over history positions, and the [R, C] snapshot tables
+from one forward-filled owner table (finish_encode). Only the
+smallest-free-slot walk is sequential, over two plain int lists.
+
 On device a configuration is then just (state: i32, mask: 2×u32) where
 mask bit j = "the call in slot j has linearized" — the fixed-width
 replacement for knossos.linear.config's per-config BitSet
@@ -26,6 +32,7 @@ from __future__ import annotations
 
 import heapq
 from dataclasses import dataclass
+from operator import attrgetter
 from typing import Optional
 
 import numpy as np
@@ -167,59 +174,57 @@ def prepare_encode(model, history, use_bulk: bool = True) -> PreparedHistory:
     # crashed *reads* before the model is known; this generalizes to
     # whatever the model family declares unconstrained (e.g. crashed
     # dequeues with unknown results).
-    crashed = np.fromiter((c.crashed for c in cs), bool, len(cs))
+    crashed = np.fromiter(map(attrgetter("crashed"), cs), bool, len(cs))
     drop = crashed & enc_wild
     model_pruned = bool(drop.any())
     if model_pruned:
         keep = ~drop
         cs = [c for c, k in zip(cs, keep) if k]
-        enc_f, enc_a0, enc_a1, enc_wild = (
-            enc_f[keep], enc_a0[keep], enc_a1[keep], enc_wild[keep])
+        enc_f, enc_a0, enc_a1, enc_wild, crashed = (
+            enc_f[keep], enc_a0[keep], enc_a1[keep], enc_wild[keep],
+            crashed[keep])
         for j, c in enumerate(cs):
             c.index = j
 
-    # events in history order; kind 0=invoke first on ties (an invoke at
-    # the same index as a return cannot precede it in a real history —
-    # indices are unique — so tie order is moot but deterministic)
-    events = []
-    for c in cs:
-        events.append((c.invoke_index, 0, c.index))
-        if not c.crashed:
-            events.append((c.complete_index, 1, c.index))
-    events.sort()
+    # Return rows: the completed calls in history order. History
+    # positions are unique, so a call's first open row is the number of
+    # returns before its invoke.
+    n = len(cs)
+    inv_pos = np.fromiter(map(attrgetter("invoke_index"), cs), np.int64, n)
+    ret_pos = np.fromiter(map(attrgetter("complete_index"), cs), np.int64, n)
+    done_ids = np.flatnonzero(~crashed)
+    ret_call = done_ids[np.argsort(ret_pos[done_ids])].astype(np.int32)
+    ret_pos = ret_pos[ret_call]
+    R = len(ret_call)
+    r_open = np.searchsorted(ret_pos, inv_pos).astype(np.int32)
+    r_close = np.full(n, R - 1, np.int32)  # crashed: open to the end
+    r_close[ret_call] = np.arange(R, dtype=np.int32)
 
     # Slot assignment: smallest free slot at invoke, freed after the
     # call's own return row (crashed calls hold theirs to the end).
+    # Inherently sequential, so it walks the merged event stream (kind
+    # 0 = invoke, 1 = return) as two plain int lists.
+    ev = np.argsort(np.concatenate([inv_pos, ret_pos]), kind="stable")
+    kinds = (ev >= n).tolist()
+    ids = np.concatenate([np.arange(n, dtype=np.int32), ret_call])[ev]
     free: list = []  # min-heap of free slots
     n_slots = 0
-    n = len(cs)
-    R = sum(1 for _, k, _ in events if k == 1)
-    r_open = np.empty(n, np.int32)    # first snapshot row while open
-    r_close = np.full(n, R - 1, np.int32)  # last row (own return / end)
-    call_slot = np.empty(n, np.int32)
-    ev_slot = np.empty(R, np.int32)
-    ret_call = np.empty(R, np.int32)
-
-    r = 0
-    for _, kind, cid in events:
-        if kind == 0:
-            s = heapq.heappop(free) if free else n_slots
-            if s == n_slots:
-                n_slots += 1
-                if n_slots > MAX_SLOTS:
-                    raise EncodeError(
-                        f"open-call window exceeds {MAX_SLOTS} slots "
-                        f"(too many concurrent/crashed calls); use the "
-                        f"host engine or partition the history per key")
-            call_slot[cid] = s
-            r_open[cid] = r
+    slot = [0] * n
+    for is_ret, cid in zip(kinds, ids.tolist()):
+        if is_ret:
+            heapq.heappush(free, slot[cid])
+        elif free:
+            slot[cid] = heapq.heappop(free)
         else:
-            s = int(call_slot[cid])
-            ev_slot[r] = s
-            ret_call[r] = cid
-            r_close[cid] = r
-            r += 1
-            heapq.heappush(free, s)
+            slot[cid] = n_slots
+            n_slots += 1
+            if n_slots > MAX_SLOTS:
+                raise EncodeError(
+                    f"open-call window exceeds {MAX_SLOTS} slots "
+                    f"(too many concurrent/crashed calls); use the "
+                    f"host engine or partition the history per key")
+    call_slot = np.array(slot, np.int32)
+    ev_slot = call_slot[ret_call]
 
     return PreparedHistory(
         cs=cs, intern=intern, spec=spec,
@@ -231,33 +236,40 @@ def prepare_encode(model, history, use_bulk: bool = True) -> PreparedHistory:
 
 def finish_encode(prep: PreparedHistory,
                   pad_slots: Optional[int] = None) -> EncodedHistory:
-    """Stage 2 of encode(): build the per-return snapshot tables by
-    INTERVAL FILL — a call occupying slot s appears identically in
-    every snapshot row from the first return after its invoke through
-    the row of its own return (snapshots are taken just before the
+    """Stage 2 of encode(): build the per-return snapshot tables.
+
+    A call occupying slot s appears identically in every snapshot row
+    from the first return after its invoke (r_open) through the row of
+    its own return (r_close: snapshots are taken just before the
     returning call is removed, so its own row includes it; crashed
-    calls stay to the end). One contiguous slice write per
-    (call, column) replaces ten full-width numpy ops per return row —
-    encode sits on the e2e bench path, so its constant matters."""
+    calls stay to the end). So each column is a run of owners, built
+    as one [R + 1, C] owner table in whole-array passes: a close mark
+    at (r_close + 1, slot), then the call id at (r_open, slot) — an open
+    wins where a call takes a slot in the same row its previous holder
+    freed — forward-filled down the rows, and every table gathered
+    through the owner (an empty cell reads -1 / False)."""
     spec, intern, cs = prep.spec, prep.intern, prep.cs
     n, R, n_slots = len(cs), prep.n_returns, prep.n_slots
     # allocate at the FINAL padded width (pad_slots may exceed n_slots)
     C = max(1, min(MAX_SLOTS, max(pad_slots or n_slots, n_slots)))
-    slot_f = np.full((R, C), -1, np.int32)
-    slot_a0 = np.full((R, C), -1, np.int32)
-    slot_a1 = np.full((R, C), -1, np.int32)
-    slot_wild = np.zeros((R, C), bool)
-    slot_occ = np.zeros((R, C), bool)
-    for cid in range(n):
-        a, b = int(prep.r_open[cid]), int(prep.r_close[cid])
-        if a > b:
-            continue  # invoked after the last return: in no snapshot
-        s = int(prep.call_slot[cid])
-        slot_occ[a:b + 1, s] = True
-        slot_f[a:b + 1, s] = prep.enc_f[cid]
-        slot_a0[a:b + 1, s] = prep.enc_a0[cid]
-        slot_a1[a:b + 1, s] = prep.enc_a1[cid]
-        slot_wild[a:b + 1, s] = prep.enc_wild[cid]
+    live = prep.r_open <= prep.r_close  # else: invoked after the last return
+    slot = prep.call_slot[live]
+    mark = np.full((R + 1, C), -1, np.int32)  # -1 no mark, -2 closed
+    mark[prep.r_close[live] + 1, slot] = -2
+    mark[prep.r_open[live], slot] = np.flatnonzero(live)
+    rows = np.where(mark != -1, np.arange(R + 1, dtype=np.int32)[:, None], 0)
+    np.maximum.accumulate(rows, axis=0, out=rows)
+    owner = np.take_along_axis(mark, rows[:R], axis=0)
+    slot_occ = owner >= 0
+    owner[~slot_occ] = n  # the empty cell, one past the last call
+
+    def gather(col, empty):
+        return np.append(col, np.array(empty, col.dtype))[owner]
+
+    slot_f = gather(prep.enc_f, -1)
+    slot_a0 = gather(prep.enc_a0, -1)
+    slot_a1 = gather(prep.enc_a1, -1)
+    slot_wild = gather(prep.enc_wild, False)
 
     return EncodedHistory(
         slot_f=slot_f, slot_a0=slot_a0, slot_a1=slot_a1,

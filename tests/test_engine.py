@@ -906,9 +906,60 @@ def test_indecisive_recheck_keeps_device_verdict(monkeypatch):
     assert "valid?" not in r           # verdict untouched
     assert "recheck indecisive" in r.get("final-paths-note", ""), r
 
+def _assert_snapshots_match_naive_oracle(e):
+    """Every column of every row of `e`'s snapshot tables against a
+    naive per-return reconstruction (the straightforward O(R*C)
+    formulation): replay the events, snapshot before each return."""
+    import heapq as hq
+    packed = [e.spec.encode_call(c.f, c.value, c.result, c.crashed)
+              for c in e.calls]
+    events = []
+    for c in e.calls:
+        events.append((c.invoke_index, 0, c.index))
+        if not c.crashed:
+            events.append((c.complete_index, 1, c.index))
+    events.sort()
+    free, n_slots, slot_of, occupant = [], 0, {}, {}
+    r = 0
+    for _, kind, cid in events:
+        if kind == 0:
+            s = hq.heappop(free) if free else n_slots
+            if s == n_slots:
+                n_slots += 1
+            slot_of[cid] = s
+            occupant[s] = cid
+        else:
+            for s in range(e.slot_f.shape[1]):
+                if s in occupant:
+                    pk = packed[occupant[s]]
+                    assert e.slot_occ[r, s], (r, s)
+                    assert e.slot_f[r, s] == pk[0]
+                    assert e.slot_a0[r, s] == pk[1]
+                    assert e.slot_a1[r, s] == pk[2]
+                    assert e.slot_wild[r, s] == pk[3]
+                else:
+                    assert not e.slot_occ[r, s], (r, s)
+                    assert e.slot_f[r, s] == -1
+                    assert e.slot_a0[r, s] == -1
+                    assert e.slot_a1[r, s] == -1
+                    assert not e.slot_wild[r, s]
+            assert e.ev_slot[r] == slot_of[cid]
+            assert e.ret_call[r] == cid
+            r += 1
+            s = slot_of[cid]
+            del occupant[s]
+            hq.heappush(free, s)
+    assert r == e.n_returns
+    assert n_slots == e.n_slots
+    for a in (e.slot_f, e.slot_a0, e.slot_a1, e.slot_wild, e.slot_occ):
+        assert a.shape == (e.n_returns, e.slot_f.shape[1])
+        assert a.flags["C_CONTIGUOUS"]
+    assert e.slot_f.dtype == e.slot_a0.dtype == np.int32
+    assert e.slot_wild.dtype == e.slot_occ.dtype == bool
+
+
 def test_encode_snapshot_interval_fill_matches_naive_oracle():
-    """encode()'s interval-fill snapshot construction vs a naive
-    per-return reconstruction (the straightforward O(R*C) formulation):
+    """encode()'s snapshot tables vs a naive per-return reconstruction:
     every column of every row must match, including slot reuse after
     returns and crashed calls holding slots to the end."""
     from jepsen_tpu.histories import (adversarial_register_history,
@@ -925,46 +976,112 @@ def test_encode_snapshot_interval_fill_matches_naive_oracle():
                   n_ops=40, n_processes=5, n_values=3, crash_p=0.1,
                   seed=2))]
     for model, h in cases:
-        e = enc_mod.encode(model, h)
-        spec = e.spec
-        packed = [spec.encode_call(c.f, c.value, c.result, c.crashed)
-                  for c in e.calls]
-        # naive reconstruction: replay events, snapshot before returns
-        import heapq as hq
-        events = []
-        for c in e.calls:
-            events.append((c.invoke_index, 0, c.index))
-            if not c.crashed:
-                events.append((c.complete_index, 1, c.index))
-        events.sort()
-        free, n_slots, slot_of, occupant = [], 0, {}, {}
-        r = 0
-        for _, kind, cid in events:
-            if kind == 0:
-                s = hq.heappop(free) if free else n_slots
-                if s == n_slots:
-                    n_slots += 1
-                slot_of[cid] = s
-                occupant[s] = cid
-            else:
-                for s in range(e.slot_f.shape[1]):
-                    if s in occupant:
-                        pk = packed[occupant[s]]
-                        assert e.slot_occ[r, s], (r, s)
-                        assert e.slot_f[r, s] == pk[0]
-                        assert e.slot_a0[r, s] == pk[1]
-                        assert e.slot_a1[r, s] == pk[2]
-                        assert e.slot_wild[r, s] == pk[3]
-                    else:
-                        assert not e.slot_occ[r, s], (r, s)
-                        assert e.slot_f[r, s] == -1
-                assert e.ev_slot[r] == slot_of[cid]
-                assert e.ret_call[r] == cid
-                r += 1
-                s = slot_of[cid]
-                del occupant[s]
-                hq.heappush(free, s)
-        assert r == e.n_returns
+        _assert_snapshots_match_naive_oracle(enc_mod.encode(model, h))
+
+
+def _mutex_history(seed, n=80):
+    import random
+    from jepsen_tpu.history import op
+    rng = random.Random(seed)
+    ops = []
+    for _ in range(n):
+        p, f = rng.randrange(4), rng.choice(["acquire", "release"])
+        ops.append(invoke_op(p, f, None))
+        ops.append(op(rng.choice(["ok", "ok", "fail", "info"]), p, f, None))
+    return _h(*ops)
+
+
+def _snapshot_case(name):
+    from jepsen_tpu.histories import (adversarial_register_history,
+                                      rand_fifo_history, rand_gset_history,
+                                      rand_queue_history)
+    from jepsen_tpu.models import FIFOQueue, GSet
+    if name == "invoked-after-last-return":
+        # p1's and p2's writes start after the last return and never
+        # complete, so they sit in no snapshot row
+        return CASRegister(), _h(
+            invoke_op(0, "write", 1), ok_op(0, "write", 1),
+            invoke_op(1, "write", 2), invoke_op(2, "write", 3),
+            info_op(1, "write", 2))
+    if name == "slot-retaken-between-adjacent-rows":
+        # p0 frees slot 0 at row 0; p2 takes it before row 1
+        return CASRegister(), _h(
+            invoke_op(0, "write", 1), invoke_op(1, "write", 2),
+            ok_op(0, "write", 1), invoke_op(2, "write", 3),
+            ok_op(1, "write", 2), ok_op(2, "write", 3))
+    if name == "adversarial-2000-k12":
+        return CASRegister(), adversarial_register_history(
+            n_ops=2000, k_crashed=12, seed=3)
+    if name == "register":
+        return Register(), rand_register_history(
+            n_ops=150, n_processes=6, n_values=3, cas=False,
+            crash_p=0.05, fail_p=0.05, seed=5)
+    if name == "cas-register":
+        return CASRegister(), rand_register_history(
+            n_ops=150, n_processes=10, n_values=5, crash_p=0.02,
+            fail_p=0.05, busy=0.8, seed=6)
+    if name == "mutex":
+        return Mutex(), _mutex_history(7)
+    if name == "gset":
+        return GSet(), rand_gset_history(
+            n_ops=150, n_processes=6, n_elements=12, crash_p=0.05, seed=8)
+    if name == "unordered-queue":
+        return UnorderedQueue(), rand_queue_history(
+            n_ops=150, n_processes=6, n_values=4, crash_p=0.05, seed=9)
+    if name == "fifo-queue":
+        return FIFOQueue(), rand_fifo_history(
+            n_ops=40, n_processes=5, n_values=3, crash_p=0.1, seed=10)
+    raise ValueError(name)
+
+
+@pytest.mark.parametrize("pad", [None, 40])
+@pytest.mark.parametrize("name", [
+    "invoked-after-last-return", "slot-retaken-between-adjacent-rows",
+    "adversarial-2000-k12", "register", "cas-register", "mutex", "gset",
+    "unordered-queue", "fifo-queue"])
+def test_encode_snapshot_fill_cases_match_naive_oracle(name, pad):
+    """The same oracle over the owner table's edge cases — a call
+    invoked after the last return, a slot freed and re-taken between
+    two adjacent return rows, a long crashed-writes key, one history of
+    each model family — at the natural width and padded wider than
+    n_slots (columns past n_slots stay empty)."""
+    model, h = _snapshot_case(name)
+    e = enc_mod.encode(model, h, pad_slots=pad)
+    if pad is not None:
+        assert e.slot_f.shape[1] == max(pad, e.n_slots) > e.n_slots
+    _assert_snapshots_match_naive_oracle(e)
+    prep = enc_mod.prepare_encode(model, h)
+    assert engine.history_digest(enc_mod.finish_encode(prep, pad)) == \
+        engine.history_digest(e)
+    if name == "invoked-after-last-return":
+        assert e.n_calls == 3 and e.n_returns == 1
+        assert e.slot_occ.sum() == 1
+    if name == "slot-retaken-between-adjacent-rows":
+        w3 = e.spec.encode_call("write", 3, None, False)
+        assert list(e.ev_slot) == [0, 1, 0]
+        assert e.slot_occ[1, 0] and e.slot_f[1, 0] == w3[0]
+        assert e.slot_a0[1, 0] == w3[1]
+    if name == "adversarial-2000-k12":
+        assert e.n_slots == 13
+
+
+# engine.history_digest of encode() on rand_register_history keys at the
+# benchmark's shape (10 processes, 120 ops, 5 values), pinned: the
+# encode's arrays must not move
+_BENCH_SHAPE_DIGESTS = {
+    2500: "00bc6f4f63d9aa74", 2501: "416ab46f117479ad",
+    2502: "3eb5fcc364ac903d", 2503: "6b60a9d1df12676d",
+    2504: "57d2c7824cdc95e1", 2505: "8cba1bfc6736cb6b",
+    2506: "2fc21283dd2c7b10", 2507: "4adb882da7cf1506"}
+
+
+@pytest.mark.parametrize("seed", sorted(_BENCH_SHAPE_DIGESTS))
+def test_encode_digest_pinned_at_benchmark_shape(seed):
+    h = rand_register_history(n_ops=120, n_processes=10, n_values=5,
+                              crash_p=0.005, fail_p=0.05, busy=0.8,
+                              seed=seed)
+    e = enc_mod.encode(CASRegister(), h)
+    assert engine.history_digest(e) == _BENCH_SHAPE_DIGESTS[seed]
 
 
 @pytest.mark.parametrize("shape", [(60, 4), (300, 8), "crashy"])

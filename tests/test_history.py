@@ -1,4 +1,5 @@
 import numpy as np
+import pytest
 
 from jepsen_tpu.history import (
     Columns, History, Op, calls, invoke_op, ok_op, fail_op, info_op,
@@ -94,6 +95,99 @@ def test_calls_keep_failed():
     assert calls(h) == []
     kept = calls(h, drop_failed=False)
     assert len(kept) == 1 and kept[0].complete_index == 1
+
+
+def _calls_per_op_oracle(history, drop_failed=True):
+    """The per-op pairing rule, one dict of open invokes by process:
+    the straightforward formulation `calls` must reproduce."""
+    from jepsen_tpu.history import Call
+    n = len(history)
+    open_by_process, out, failed = {}, [], set()
+    for i, o in enumerate(history):
+        p = o.get("process")
+        if not isinstance(p, int):
+            continue
+        if o.is_invoke:
+            c = Call(index=-1, process=p, f=o.get("f"),
+                     value=o.get("value"), result=None, invoke_index=i,
+                     complete_index=n, crashed=True)
+            open_by_process[p] = c
+            out.append(c)
+            continue
+        c = open_by_process.pop(p, None)
+        if c is None:
+            continue
+        if o.is_ok:
+            c.result, c.complete_index, c.crashed = o.get("value"), i, False
+        elif o.is_fail:
+            c.complete_index, c.crashed = i, False
+            failed.add(id(c))
+    if drop_failed:
+        out = [c for c in out if id(c) not in failed]
+    for j, c in enumerate(out):
+        c.index = j
+    return out
+
+
+def _messy_history(seed, n=300):
+    """Seeded client traffic with :fail and :info completions, nemesis
+    ops, a non-int process, stray completions, and processes that invoke
+    again without completing."""
+    import random
+    rng = random.Random(seed)
+    ops, busy = [], {}
+    for _ in range(n):
+        r = rng.random()
+        if r < 0.05:
+            ops.append(Op(type=rng.choice(["invoke", "info"]),
+                          process="nemesis", f="start", value=None))
+            continue
+        if r < 0.08:
+            ops.append(Op(type=rng.choice(["invoke", "ok"]),
+                          process=rng.choice(["p9", 2.0, None]),
+                          f="read", value=None))
+            continue
+        p = rng.randrange(6)
+        if p in busy and rng.random() < 0.85:
+            f, v = busy.pop(p)
+            t = rng.choices(["ok", "fail", "info"], [6, 2, 1])[0]
+            ops.append(Op(type=t, process=p, f=f,
+                          value=rng.randrange(4) if f == "read" else v))
+        elif rng.random() < 0.05:
+            ops.append(Op(type="ok", process=p, f="read", value=1))
+        else:  # a fresh invoke, even if one is still open
+            f = rng.choice(["read", "write", "cas"])
+            v = (None if f == "read" else rng.randrange(4) if f == "write"
+                 else [rng.randrange(4), rng.randrange(4)])
+            busy[p] = (f, v)
+            ops.append(Op(type="invoke", process=p, f=f, value=v))
+    return History.wrap(ops).index()
+
+
+@pytest.mark.parametrize("drop_failed", [True, False])
+@pytest.mark.parametrize("seed", [0, 1, 2, 3])
+def test_calls_matches_per_op_pairing_oracle(seed, drop_failed):
+    h = _messy_history(seed)
+    got = calls(h, drop_failed=drop_failed)
+    want = _calls_per_op_oracle(h, drop_failed=drop_failed)
+    assert [vars(c) for c in got] == [vars(c) for c in want]
+    for c in got:  # plain Python ints, as the oracle builds them
+        assert type(c.index) is int and type(c.invoke_index) is int
+        assert type(c.complete_index) is int and type(c.crashed) is bool
+    kinds = {(c.crashed, c.result is None) for c in got}
+    assert (True, True) in kinds and (False, False) in kinds
+
+
+def test_calls_edge_histories():
+    assert calls(History()) == []
+    assert calls(_h(info_op("nemesis", "start", None))) == []
+    h = _h(invoke_op(0, "write", 1), invoke_op(0, "write", 2),
+           ok_op(0, "write", 2), ok_op(0, "write", 3))
+    got = calls(h)
+    assert [vars(c) for c in got] == [vars(c) for c in
+                                      _calls_per_op_oracle(h)]
+    assert got[0].crashed and got[0].complete_index == 4
+    assert not got[1].crashed and got[1].complete_index == 2
 
 
 # ---------------------------------------------------- npz sidecar
