@@ -1,8 +1,9 @@
 """The comparison that decides `correct`.
 
 Every check the window completed is compared with the reference's
-answer for the same pool entry. Two numbers count disagreements, and
-each limit is 0: the comparison is exact.
+answer for the same pool entry. The reference is the configuration's
+`model`, found by name (`references/<model>.py`). Two numbers count
+disagreements, and each limit is 0: the comparison is exact.
 
 - `keys_wrong`: keys, over all checks, whose answer differs from the
   reference's: the verdict, and for an invalid key its failing op
@@ -13,20 +14,19 @@ each limit is 0: the comparison is exact.
   any key, or that raised instead of answering;
 - `off_device_keys`: keys, over every check of the run (set-up's
   included), that the device did not answer: a host fallback keeps the
-  verdict, so only this number sees it (`program.path_notes`).
+  verdict, so only this number sees it (the entry's `path_notes`).
 """
 
 from __future__ import annotations
 
-import reference
-
 LIMITS = {"keys_wrong": 0, "checks_wrong": 0, "off_device_keys": 0}
 
 
-def reference_answers(entry: list, crashed_dropped: bool = False) -> dict:
-    """The reference's answers for one pool entry ([(key, ops)]), in
-    the form `program.answers` gives."""
-    keys = {k: reference.check(ops, crashed_dropped) for k, ops in entry}
+def reference_answers(ref, entry: list, crashed_dropped: bool = False) \
+        -> dict:
+    """The answers of the reference module `ref` for one pool entry
+    ([(key, ops)]), in the form an entry's `answers` gives."""
+    keys = {k: ref.check(ops, crashed_dropped) for k, ops in entry}
     valid = all(a["valid?"] for a in keys.values())
     failures = (None if entry[0][0] is None
                 else [k for k, _ in entry if keys[k]["valid?"] is False])

@@ -25,6 +25,7 @@ import sys
 
 import compare
 import harness
+import named
 import traffic
 
 
@@ -36,9 +37,11 @@ def control_numbers(root: str, cell: str, seed: int, checks: int = 0,
     mix = traffic.load(root, "traffic", entry["traffic"])
     if shrink is not None:
         cfg, mix = shrink(dict(cfg), dict(mix))
-    pool = traffic.pool(cfg, mix, seed)
-    refs = [compare.reference_answers(e) for e in pool]
-    got = [compare.reference_answers(e, crashed_dropped=True) for e in pool]
+    pool = traffic.pool(root, cfg, mix, seed)
+    ref = named.load(root, "references", cfg["model"])
+    refs = [compare.reference_answers(ref, e) for e in pool]
+    got = [compare.reference_answers(ref, e, crashed_dropped=True)
+           for e in pool]
     n = checks or len(pool)
     return compare.compare([(i % len(pool), got[i % len(pool)])
                             for i in range(n)], refs)

@@ -3,22 +3,25 @@
     python benchmark/run.py --workload <cell> --seed <n> --seconds <s> \
         --trace <0|1>
 
-Set-up makes the cell's pool of histories from the seed, builds the
-checker its configuration names, and checks every history of the pool
-once, which compiles (or loads from JAX's persistent cache) every
-program the window will run. The window is a closed loop: one checker,
-the next check starts when the last returns, cycling through the pool
-for `--seconds` (the check under way at the deadline finishes and
-counts). The program keeps no result or encode cache on this path, so
-a repeat costs full work. Then the device's peak memory is read, the
-reference answers each pool entry, and every check of the window is
-compared with it; every key of every check, set-up's too, has to have
-been answered on the device.
+Set-up makes the cell's pool of histories from the seed (the
+configuration's generator), builds the checker the configuration's
+entry names, and checks every history of the pool once, which compiles
+(or loads from JAX's persistent cache) every program the window will
+run. The window is a closed loop: one checker, the next check starts
+when the last returns, cycling through the pool for `--seconds` (the
+check under way at the deadline finishes and counts). The program keeps
+no result or encode cache on this path, so a repeat costs full work.
+Then the device's peak memory is read, the reference (the
+configuration's model) answers each pool entry, and every check of the
+window is compared with it; every key of every check, set-up's too, has
+to have been answered on the device.
+
+The configuration's `entry`, `generator` and `model` are found by name
+(`named.py`), as each metric is (`metrics/<name>.py`).
 
 `--trace 0` prints the cell's end-to-end metrics; `--trace 1` turns on
 the program's spans and a profiler trace of the window's first
 `PROFILE_SECONDS`, and prints the per-layer metrics and a breakdown.
-Each metric is read by `metrics/<name>.py`, found by name.
 
 The last lines of standard error are the compared numbers beside their
 limits; the last line of standard output is one JSON object.
@@ -27,7 +30,6 @@ limits; the last line of standard output is one JSON object.
 from __future__ import annotations
 
 import argparse
-import importlib.util
 import json
 import os
 import shutil
@@ -94,12 +96,8 @@ def cell_metrics(spec: dict, cell: str, trace: bool) -> list:
 
 
 def load_reader(root: str, name: str):
-    path = os.path.join(root, "benchmark", "metrics", name + ".py")
-    mod_spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_").replace("-", "_"), path)
-    mod = importlib.util.module_from_spec(mod_spec)
-    mod_spec.loader.exec_module(mod)
-    return mod.read
+    import named
+    return named.load(root, "metrics", name).read
 
 
 def device_info(jax, chips: int, require_accelerator: bool):
@@ -147,14 +145,15 @@ def run_cell(root: str, cell: str, seed: int, seconds: float, trace: bool,
     jax.monitoring.register_event_duration_secs_listener(watch)
 
     import compare
-    import program
-    pool = traffic_mod.pool(cfg, mix, seed)
-    histories = [program.history(e) for e in pool]
+    import named
+    program = named.load(root, "entries", cfg["entry"])
+    pool = traffic_mod.pool(root, cfg, mix, seed)
+    histories = [program.wrap(e) for e in pool]
     n_ops = [traffic_mod.n_invocations(e) for e in pool]
-    checker = program.checker(cfg["entry"])
+    checker = program.checker(cfg)
     notes = {"closures": set(), "off_device": 0}
     for h in histories:                 # warm every shape of the pool
-        program_notes(notes, checker.check({}, h, {}))
+        program_notes(program, notes, program.check(checker, h))
 
     run = Run()
     run.setup_compile_s = watch.setup_s
@@ -175,7 +174,7 @@ def run_cell(root: str, cell: str, seed: int, seconds: float, trace: bool,
     while now < deadline:
         k = i % len(histories)
         try:
-            r = checker.check({}, histories[k], {})
+            r = program.check(checker, histories[k])
         except Exception as err:  # noqa: BLE001 - an unanswered check is counted
             print(f"check {i} (pool entry {k}) raised: {err!r}",
                   file=sys.stderr)
@@ -202,11 +201,12 @@ def run_cell(root: str, cell: str, seed: int, seconds: float, trace: bool,
     observed = []
     for k, r in results:
         if r is not None:
-            program_notes(notes, r)
+            program_notes(program, notes, r)
         observed.append((k, None if r is None
                          else program.answers(r, pool[k])))
     del results
-    refs = [compare.reference_answers(e) for e in pool]
+    ref = named.load(root, "references", cfg["model"])
+    refs = [compare.reference_answers(ref, e) for e in pool]
     numbers = compare.compare(observed, refs, notes["off_device"])
     ok = compare.correct(numbers)
     failed = numbers["checks_wrong"]["value"]
@@ -234,8 +234,7 @@ def run_cell(root: str, cell: str, seed: int, seconds: float, trace: bool,
     return out
 
 
-def program_notes(notes: dict, result: dict) -> None:
-    import program
+def program_notes(program, notes: dict, result: dict) -> None:
     n = program.path_notes(result)
     notes["closures"].update(n["closures"])
     notes["off_device"] += n["off_device"]

@@ -1,71 +1,47 @@
-"""The boundary to the system under test.
-
-Builds the checker a configuration names, wraps the generators' plain
-histories into the program's history type, and reads back from each
-result the answers that are compared with the reference. Nothing else
-of the benchmark imports the program.
-
-Entries:
-- `independent`: `independent.checker(linearizable(CASRegister(),
-  algorithm="jax"))`, the checker the Tendermint suite builds; the
-  history's values are `KV(key, value)` tuples;
-- `single`: `linearizable(CASRegister(), algorithm="jax")` on one key.
+"""What the checker entries (`entries/<name>.py`) share at the
+boundary to the system under test: the model a configuration names,
+and the reading of a result back into the answers that are compared
+with the reference. Only the entries import the program.
 """
 
 from __future__ import annotations
 
-import reference
+import json
+
+# a configuration's `model` -> the program's model class, with its
+# defaults (the names `jepsen serve --checker --model` takes)
+MODELS = {"register": "Register", "cas-register": "CASRegister",
+          "mutex": "Mutex", "gset": "GSet", "fifo": "FIFOQueue",
+          "uqueue": "UnorderedQueue"}
+
+FALLBACK_NOTES = ("device-fallback", "resilience", "fallback")
 
 
-def checker(entry: str):
-    from jepsen_tpu import independent
-    from jepsen_tpu.checker import linearizable
-    from jepsen_tpu.models import CASRegister
-    lin = linearizable(CASRegister(), algorithm="jax")
-    if entry == "independent":
-        return independent.checker(lin)
-    if entry == "single":
-        return lin
-    raise ValueError(f"unknown checker entry {entry!r}")
+def model(cfg: dict):
+    from jepsen_tpu import models
+    return getattr(models, MODELS[cfg["model"]])()
 
 
-def history(entry: list):
-    """The program's history for one pool entry ([(key, ops)])."""
-    from jepsen_tpu.history import History
-    from jepsen_tpu.independent import KV
-    if len(entry) == 1 and entry[0][0] is None:
-        return History.wrap(dict(o) for o in entry[0][1]).index()
-    ops = []
-    for k, key_ops in entry:
-        for o in key_ops:
-            o = dict(o)
-            o["value"] = KV(k, o.get("value"))
-            ops.append(o)
-    return History.wrap(ops).index()
+def linearizable(cfg: dict):
+    """`linearizable(<model>, algorithm="jax")`, the checker a Jepsen
+    suite builds."""
+    from jepsen_tpu.checker import linearizable as lin
+    return lin(model(cfg), algorithm="jax")
 
 
-def _key_answer(r: dict) -> dict:
+def canon(v):
+    """A value in the form both sides are compared in."""
+    return json.loads(json.dumps(v))
+
+
+def key_answer(r: dict) -> dict:
     a = {"valid?": r.get("valid?")}
     if a["valid?"] is False:
         op = r.get("op") or {}
         a["fail-event"] = r.get("fail-event")
-        a["op"] = {k: reference.canon(op.get(k))
+        a["op"] = {k: canon(op.get(k))
                    for k in ("process", "f", "value", "index")}
     return a
-
-
-def answers(result: dict, entry: list) -> dict:
-    """{"valid?", "failures", "keys": {key: answer}} of one result."""
-    if entry[0][0] is None:
-        a = _key_answer(result)
-        return {"valid?": a["valid?"], "failures": None, "keys": {None: a}}
-    return {"valid?": result.get("valid?"),
-            "failures": list(result.get("failures") or []),
-            "keys": {k: _key_answer(r)
-                     for k, r in (result.get("results") or {}).items()}}
-
-
-FALLBACK_NOTES = ("device-fallback", "resilience", "fallback")
 
 
 def path_notes(result: dict) -> dict:

@@ -8,6 +8,7 @@ import os
 import pytest
 
 import generators
+import named
 import traffic
 from conftest import ROOT
 from jepsen_tpu import histories
@@ -106,7 +107,7 @@ def test_every_seed_gives_the_same_shapes_in_another_order():
                               open_window(ops)) for k, ops in e)
                       for e in pool)
 
-    a, b = (traffic.pool(c, mix, s) for s in (1, 2**40 + 7))
+    a, b = (traffic.pool(ROOT, c, mix, s) for s in (1, 2**40 + 7))
     assert shapes(a) == shapes(b)
     assert a != b
 
@@ -117,15 +118,16 @@ def test_keys_are_the_generators_draws_as_they_come():
     # keep the source's distribution
     c = cfg("tendermint-cas")
     mix = dict(traffic.load(ROOT, "traffic", "10k-ops"), faults={})
-    seeds = traffic._sub_seeds(mix["structure_seed"], mix["pool"])
-    entry = traffic.register_entry(c, mix, seeds[0], 0)
+    seeds = traffic.sub_seeds(mix["structure_seed"], mix["pool"])
+    register = named.load(ROOT, "gens", "register")
+    entry = register.entry(c, mix, seeds[0], 0)
     assert [ops for _, ops in entry] == [
-        traffic._register_key(c, s)
-        for s in traffic._sub_seeds(seeds[0], mix["keys"] + 2)[:mix["keys"]]]
+        register._register_key(c, s)
+        for s in traffic.sub_seeds(seeds[0], mix["keys"] + 2)[:mix["keys"]]]
 
 
 def test_reordering_keeps_every_verdict_and_fail_event():
-    import reference
+    reference = named.load(ROOT, "references", "cas-register")
     c = cfg("tendermint-cas")
     mix = traffic.load(ROOT, "traffic", "short-run")
 
@@ -138,5 +140,5 @@ def test_reordering_keeps_every_verdict_and_fail_event():
                             r.get("op", {}).get("index")))
         return sorted(out, key=str)
 
-    assert answers(traffic.pool(c, mix, 5)) == answers(
-        traffic.pool(c, mix, 2**33 + 1))
+    assert answers(traffic.pool(ROOT, c, mix, 5)) == answers(
+        traffic.pool(ROOT, c, mix, 2**33 + 1))

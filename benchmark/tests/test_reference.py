@@ -4,7 +4,10 @@ program's host engine on generated ones."""
 import pytest
 
 import generators
-import reference
+import named
+from conftest import ROOT
+
+reference = named.load(ROOT, "references", "cas-register")
 
 
 def op(typ, p, f, v, **kw):
