@@ -23,13 +23,24 @@ the host for small graphs, and — the TPU path — boolean transitive
 closure by repeated squaring of the adjacency matrix on the MXU
 (`jnp.dot` over bfloat16 lifts reachability onto the systolic array;
 SCC = R & R.T), which turns the irregular graph walk into dense matmuls
-for histories with thousands of transactions.
+for histories with thousands of transactions. The device path pads the
+node and edge counts to buckets (one compiled program per bucket),
+scatters int32 edge arrays into the adjacency on the device and fetches
+one component label per node; above `DEVICE_SCC_MAX_NODES` it refuses,
+and `sccs` answers on the host and names that engine.
 """
 
 from __future__ import annotations
 
+import itertools
+import logging
+import math
 from collections import deque
 from typing import Callable, Dict, List, Optional, Sequence, Set, Tuple
+
+from jepsen_tpu import obs
+
+log = logging.getLogger(__name__)
 
 # Edge types, in explanation-priority order.
 WW, WR, RW, RT, PROC = "ww", "wr", "rw", "rt", "process"
@@ -37,6 +48,16 @@ WW, WR, RW, RT, PROC = "ww", "wr", "rw", "rt", "process"
 # Device SCC pays off once the adjacency matrix is big enough to fill the
 # MXU; below this we stay on host.
 _DEVICE_SCC_MIN_NODES = 1024
+
+# The most nodes the dense closure takes. It holds two bfloat16 n x n
+# matrices (XLA fuses the squaring's float32 accumulation into its
+# output): 12.25 GiB at 57,344 nodes, of a TPU v5e's 15.75 GiB; at
+# 65,536 the compiler refuses it for HBM.
+DEVICE_SCC_MAX_NODES = 57344
+
+#: the engines `sccs` names: the dense closure on the device, Tarjan on
+#: the host
+DEVICE, HOST = "device", "host"
 
 
 class Graph:
@@ -144,13 +165,19 @@ _scc_closure_jit = None  # memoized jit wrapper (see _get_scc_closure)
 
 
 def _get_scc_closure():
-    """The jitted transitive-closure program, built ONCE and memoized
-    in a module global. jax stays a lazy import (this module must be
-    usable with no backend), but the wrapper must not be re-created per
-    device_sccs call — a fresh jax.jit each call would never reuse the
-    compile cache (found by `jepsen-tpu lint`, recompile-closure-
-    capture); the memo makes the jit effectively module-level, so the
-    suppression below records intent, not a hazard."""
+    """The jitted closure program, built ONCE and memoized in a module
+    global. jax stays a lazy import (this module must be usable with no
+    backend), but the wrapper must not be re-created per device_sccs
+    call — a fresh jax.jit each call would never reuse the compile cache
+    (found by `jepsen-tpu lint`, recompile-closure-capture); the memo
+    makes the jit effectively module-level, so the suppression below
+    records intent, not a hazard.
+
+    `labels(src, dst, n, steps)`: the [n, n] adjacency scattered from
+    the int32 edge arrays (an index of n or more is padding and is
+    dropped), R := A | I squared `steps` times (boolean matmul =
+    bfloat16 dot > 0, float32 accumulation), and each node's label: the
+    smallest j with R[i, j] & R[j, i]."""
     global _scc_closure_jit
     if _scc_closure_jit is not None:
         return _scc_closure_jit
@@ -158,61 +185,120 @@ def _get_scc_closure():
     import jax.numpy as jnp
     from jax import lax
 
-    def closure(adj, steps: int):
-        r = jnp.minimum(adj + jnp.eye(adj.shape[0], dtype=adj.dtype), 1.0)
+    def labels(src, dst, n: int, steps: int):
+        diag = jnp.arange(n)
+        r = jnp.zeros((n, n), jnp.bfloat16)
+        r = r.at[src, dst].set(1, mode="drop").at[diag, diag].set(1)
 
         def body(_, r):
-            rr = jnp.dot(r.astype(jnp.bfloat16), r.astype(jnp.bfloat16),
-                         preferred_element_type=jnp.float32)
-            return jnp.minimum(rr, 1.0).astype(adj.dtype)
+            rr = jnp.dot(r, r, preferred_element_type=jnp.float32)
+            return jnp.minimum(rr, 1.0).astype(jnp.bfloat16)
 
         r = lax.fori_loop(0, steps, body, r)
-        return jnp.logical_and(r > 0, r.T > 0)
+        both = jnp.logical_and(r > 0, r.T > 0)
+        return jnp.argmax(both, axis=1).astype(jnp.int32)
 
     # jepsen-lint: disable=recompile-closure-capture
-    _scc_closure_jit = jax.jit(closure, static_argnums=1)
+    _scc_closure_jit = jax.jit(labels, static_argnums=(2, 3))
     return _scc_closure_jit
 
 
-def device_sccs(g: Graph) -> List[List[int]]:
-    """SCCs via MXU transitive closure: R := A | I, square ceil(log2 n)
-    times (boolean matmul = bfloat16 dot > 0), SCC membership = R & R.T.
-    One XLA program; the graph walk becomes dense systolic-array work."""
-    import math
+def node_bucket(n: int) -> int:
+    """The padded node count the closure runs at: the next power of two
+    (at least 128) up to 1,024, then the next multiple of 1,024."""
+    if n <= 1024:
+        return max(128, 1 << max(0, n - 1).bit_length())
+    return -(-n // 1024) * 1024
 
+
+def edge_bucket(e: int) -> int:
+    """The padded edge count: the next power of two, at least 1,024."""
+    return max(1024, 1 << max(0, e - 1).bit_length())
+
+
+def closure_steps(n_pad: int) -> int:
+    """Squarings that close paths of every length in an n_pad-node
+    graph: ceil(log2 n_pad)."""
+    return max(1, math.ceil(math.log2(max(2, n_pad))))
+
+
+def closure_flops(n_pad: int) -> int:
+    """Multiply-adds of one closure, counted as 2 flops each."""
+    return closure_steps(n_pad) * 2 * n_pad ** 3
+
+
+def edge_arrays(g: Graph):
+    """(ids, src, dst): the graph's node ids in sorted order and its
+    edges as int32 positions into them, padded to `edge_bucket` with
+    the out-of-range position `node_bucket(len(ids))`."""
     import numpy as np
 
-    ids = sorted(g.nodes())
-    n = len(ids)
+    ids = np.array(sorted(g.out), dtype=np.int64)
+    n_pad = node_bucket(len(ids))
+    fan = np.fromiter(map(len, g.out.values()), np.int64, count=len(g.out))
+    e = int(fan.sum())
+    heads = np.repeat(np.fromiter(g.out, np.int64, count=len(g.out)), fan)
+    tails = np.fromiter(itertools.chain.from_iterable(g.out.values()),
+                        np.int64, count=e)
+    src = np.full(edge_bucket(e), n_pad, dtype=np.int32)
+    dst = np.full(edge_bucket(e), n_pad, dtype=np.int32)
+    src[:e] = np.searchsorted(ids, heads)
+    dst[:e] = np.searchsorted(ids, tails)
+    return ids.tolist(), src, dst
+
+
+def device_sccs(g: Graph) -> List[List[int]]:
+    """SCCs (more than one node) via the MXU transitive closure: the
+    edge arrays go to the device, the adjacency is built there at the
+    padded node count, closed by ceil(log2 n_pad) squarings, and only
+    the n int32 component labels come back. Padding nodes have no
+    edges, so each is its own label and adds no component. Raises
+    ValueError above `DEVICE_SCC_MAX_NODES`."""
+    import jax
+    import numpy as np
+
+    n = len(g)
+    if n > DEVICE_SCC_MAX_NODES:
+        raise ValueError(
+            f"{n} nodes: over the device closure's ceiling of "
+            f"{DEVICE_SCC_MAX_NODES}")
     if n == 0:
         return []
-    pos = {v: i for i, v in enumerate(ids)}
-    a = np.zeros((n, n), dtype=np.float32)
-    for u, bs in g.out.items():
-        for v in bs:
-            a[pos[u], pos[v]] = 1.0
-    # static trip count computed host-side (it was np math inside the
-    # traced closure before — legal but a purity-rule exception for no
-    # gain)
-    steps = max(1, math.ceil(math.log2(max(2, n))))
-    s = np.asarray(_get_scc_closure()(a, steps))
-    seen: Set[int] = set()
-    sccs: List[List[int]] = []
-    for i in range(n):
-        if i in seen:
-            continue
-        members = np.nonzero(s[i])[0]
-        comp = [ids[j] for j in members]
-        seen.update(int(j) for j in members)
-        if len(comp) > 1:
-            sccs.append(comp)
-    return sccs
+    with obs.span("elle.sccs.place"):
+        ids, src, dst = edge_arrays(g)
+        src, dst = jax.device_put(src), jax.device_put(dst)
+    n_pad = node_bucket(n)
+    steps = closure_steps(n_pad)
+    with obs.span("elle.sccs.device", n=n_pad, edges=int(src.shape[0])):
+        lab = _get_scc_closure()(src, dst, n_pad, steps)
+        lab.block_until_ready()
+    with obs.span("elle.sccs.fetch"):
+        lab = np.asarray(lab)[:n]
+    obs.counter("elle.scc.padded_nodes").inc(n_pad)
+    obs.counter("elle.scc.closure_calls").inc()
+    obs.counter("elle.scc.closure_flops").inc(closure_flops(n_pad))
+    order = np.argsort(lab, kind="stable")
+    cuts = np.flatnonzero(np.diff(lab[order])) + 1
+    return [[ids[j] for j in comp.tolist()]
+            for comp in np.split(order, cuts) if len(comp) > 1]
 
 
-def sccs(g: Graph) -> List[List[int]]:
-    if len(g) >= _DEVICE_SCC_MIN_NODES:
-        return device_sccs(g)
-    return tarjan_sccs(g)
+def sccs(g: Graph) -> Tuple[List[List[int]], str]:
+    """(SCCs with more than one node, the engine that found them): the
+    device closure from `_DEVICE_SCC_MIN_NODES` nodes up to
+    `DEVICE_SCC_MAX_NODES`, Tarjan on the host otherwise. Over the
+    ceiling it logs why the host answers."""
+    n = len(g)
+    obs.counter("elle.nodes").inc(n)
+    obs.counter("elle.edges").inc(sum(len(bs) for bs in g.out.values()))
+    engine = DEVICE if n >= _DEVICE_SCC_MIN_NODES else HOST
+    if engine == DEVICE and n > DEVICE_SCC_MAX_NODES:
+        log.warning("elle: %d nodes are over the device closure's ceiling "
+                    "of %d; SCCs found on the host", n, DEVICE_SCC_MAX_NODES)
+        engine = HOST
+    with obs.span("elle.sccs", engine=engine, nodes=n):
+        comps = device_sccs(g) if engine == DEVICE else tarjan_sccs(g)
+    return comps, engine
 
 
 # --------------------------------------------------------------- cycles
@@ -310,10 +396,18 @@ def cycle_anomalies(g: Graph, explainer: Optional[Callable] = None,
                     by_id: Optional[Dict[int, dict]] = None) -> Dict[str, list]:
     """Classify every SCC into the most severe anomaly classes it exhibits.
     Returns anomaly-name -> list of {"cycle": [...ids...], "steps": [...]}."""
+    return classify(g, sccs(g)[0], explainer, by_id)
+
+
+def classify(g: Graph, comps: List[List[int]],
+             explainer: Optional[Callable] = None,
+             by_id: Optional[Dict[int, dict]] = None) -> Dict[str, list]:
+    """`cycle_anomalies` over SCCs already found: one case per SCC, in
+    the most severe class it has a cycle of."""
     if explainer is None:
         explainer = _default_explainer(by_id or {})
     found: Dict[str, list] = {}
-    for comp in sccs(g):
+    for comp in comps:
         for name, types, required, exactly_one in _CYCLE_SPECS:
             if exactly_one is not None:
                 cyc = find_cycle_with_one(g, comp, exactly_one,

@@ -16,7 +16,7 @@ from __future__ import annotations
 
 from typing import Dict, List, Optional, Set, Tuple
 
-from jepsen_tpu import elle
+from jepsen_tpu import elle, obs
 from jepsen_tpu.elle import Graph, RW, WR, WW, txn as txn_mod
 
 DEFAULT_ANOMALIES = ["G1", "G2"]
@@ -159,18 +159,54 @@ def graph(oks: List[dict]) -> Tuple[Graph, Dict, Dict, List[dict]]:
 
 def check(opts: Optional[Dict], history) -> Dict:
     """elle.list-append/check equivalent. opts: anomalies (default
-    [G1 G2]), additional-graphs ("realtime"/"process")."""
+    [G1 G2]), additional-graphs ("realtime"/"process"). The result's
+    "scc" names the engine that found the SCCs (`elle.sccs`)."""
     o = opts or {}
+    with obs.span("elle.check"):
+        return _check(o, history)
+
+
+def _check(o: Dict, history) -> Dict:
     wanted = expand_anomalies(o.get("anomalies", DEFAULT_ANOMALIES))
-    oks = txn_mod.ok_txns(history)
-    by_id = {t["_id"]: t for t in oks}
     anomalies: Dict[str, list] = {}
+    with obs.span("elle.scan"):
+        oks = txn_mod.ok_txns(history)
+        by_id = {t["_id"]: t for t in oks}
+        if "internal" in wanted:
+            cases = internal_cases(oks)
+            if cases:
+                anomalies["internal"] = cases
+        _read_anomalies(history, oks, wanted, anomalies)
 
-    if "internal" in wanted:
-        cases = internal_cases(oks)
-        if cases:
-            anomalies["internal"] = cases
+    with obs.span("elle.graph"):
+        g, _appender, _orders, incompat = graph(oks)
+        if incompat:
+            anomalies["incompatible-order"] = incompat
+        extra = o.get("additional-graphs") or []
+        if "realtime" in extra:
+            g.merge(elle.realtime_graph(oks))
+        if "process" in extra:
+            g.merge(elle.process_graph(oks))
 
+    comps, engine = elle.sccs(g)
+    with obs.span("elle.cycles", sccs=len(comps)):
+        cyc = elle.classify(g, comps, by_id=by_id)
+    for name, cases in cyc.items():
+        if name in wanted:
+            anomalies[name] = cases
+
+    return {
+        "valid?": not anomalies,
+        "anomaly-types": sorted(anomalies),
+        "anomalies": anomalies,
+        "scc": engine,
+    }
+
+
+def _read_anomalies(history, oks: List[dict], wanted: Set[str],
+                    anomalies: Dict[str, list]) -> None:
+    """G1a (a read of a failed append) and G1b (a read ending in
+    another txn's non-final append) cases, into `anomalies`."""
     failed = txn_mod.failed_writes(history, "append")
     inter = txn_mod.intermediate_writes(oks, "append")
     for t in oks:
@@ -188,27 +224,6 @@ def check(opts: Optional[Dict], history) -> Dict:
                         and src["_id"] != t["_id"] and list(v)[-1] == x):
                     anomalies.setdefault("G1b", []).append(
                         {"op": dict(t), "mop": [f, k, list(v)], "value": x})
-
-    g, _appender, _orders, incompat = graph(oks)
-    if incompat:
-        anomalies["incompatible-order"] = incompat
-
-    extra = o.get("additional-graphs") or []
-    if "realtime" in extra:
-        g.merge(elle.realtime_graph(oks))
-    if "process" in extra:
-        g.merge(elle.process_graph(oks))
-
-    cyc = elle.cycle_anomalies(g, by_id=by_id)
-    for name, cases in cyc.items():
-        if name in wanted:
-            anomalies[name] = cases
-
-    return {
-        "valid?": not anomalies,
-        "anomaly-types": sorted(anomalies),
-        "anomalies": anomalies,
-    }
 
 
 def gen(opts: Optional[Dict] = None):

@@ -48,6 +48,7 @@ already resident.
 
 from __future__ import annotations
 
+import contextlib
 import json
 import logging
 import os
@@ -222,24 +223,47 @@ def _versions() -> Tuple[str, str]:
     return jax.__version__, jl
 
 
+_NO_CACHE_LOCK = threading.Lock()
+_no_cache_scopes = [0, None]     # open scopes; jax's memo, saved by the first
+
+
+@contextlib.contextmanager
 def _no_persistent_cache():
     """Scope under which registry compiles bypass jax's persistent
     compilation cache (``jax_compilation_cache_dir``). An executable
     satisfied from that cache does not survive a
     ``serialize_executable`` round-trip on the CPU backend — the
     deserialized program aborts with "Symbols not found" — so a
-    ``.jprog`` persisted from a cache-hit executable is poisoned and
-    every restart that preloads it degrades to a fresh compile. The
+    ``.jprog`` persisted from a cache-hit executable is poisoned. The
     registry's own disk layer already covers these programs, so the
-    global cache is redundant here anyway. The config state is
-    context-managed (thread-local overlay): concurrent non-registry
-    jits are unaffected."""
+    global cache is redundant here anyway.
+
+    jax decides once per process whether the cache is used
+    (``compilation_cache.is_cache_used`` keeps its first answer), so the
+    thread-local ``enable_compilation_cache(False)`` overlay alone does
+    not reach a process that has compiled through the cache before: the
+    memo reads "unused" while any scope is open and is restored when
+    the last one closes. A jit on another thread meanwhile compiles
+    without the cache: it costs time, never a wrong program."""
     try:
+        from jax._src import compilation_cache as cc
         from jax._src.config import enable_compilation_cache
-        return enable_compilation_cache(False)
     except Exception:  # noqa: BLE001 — private API; degrade to no-op
-        import contextlib
-        return contextlib.nullcontext()
+        yield
+        return
+    with _NO_CACHE_LOCK:
+        if _no_cache_scopes[0] == 0:
+            _no_cache_scopes[1] = (cc._cache_checked, cc._cache_used)
+        _no_cache_scopes[0] += 1
+        cc._cache_checked, cc._cache_used = True, False
+    try:
+        with enable_compilation_cache(False):
+            yield
+    finally:
+        with _NO_CACHE_LOCK:
+            _no_cache_scopes[0] -= 1
+            if _no_cache_scopes[0] == 0:
+                cc._cache_checked, cc._cache_used = _no_cache_scopes[1]
 
 
 # XLA:CPU cannot serialize an executable that has already run (a sort

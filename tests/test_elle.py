@@ -290,3 +290,227 @@ class TestWorkloadCheckers:
         r = cycle_wl.checker(analyzer).check({}, H([]))
         assert r["valid?"] is False
         assert "G0" in r["anomaly-types"]
+
+
+# ------------------------------------------------- device SCC closure
+
+
+def _random_graph(n, seed, edges_per_node=1.6):
+    import random
+
+    r = random.Random(seed)
+    g = Graph()
+    for i in range(n):
+        g.add_node(i)
+    for _ in range(int(n * edges_per_node)):
+        g.add(r.randrange(n), r.randrange(n), WW)
+    return g
+
+
+def _parts(comps):
+    return sorted(sorted(c) for c in comps)
+
+
+class TestDeviceSCC:
+    @pytest.mark.parametrize("n", [1023, 1024, 1025])
+    def test_bucketed_closure_matches_tarjan_at_a_bucket_edge(self, n):
+        g = _random_graph(n, seed=n)
+        assert elle.node_bucket(n) == (1024 if n <= 1024 else 2048)
+        want = _parts(elle.tarjan_sccs(g))
+        assert want  # the seeds give cycles to find
+        assert _parts(elle.device_sccs(g)) == want
+
+    def test_a_ring_needs_every_squaring(self, monkeypatch):
+        # node i reaches i - 1 only over 1,499 edges: 11 squarings of
+        # the 2,048 bucket close it, 10 (paths up to 1,024) do not
+        n = 1500
+        g = Graph()
+        for i in range(n):
+            g.add(i, (i + 1) % n, WW)
+        assert elle.closure_steps(elle.node_bucket(n)) == 11
+        assert _parts(elle.device_sccs(g)) == [list(range(n))]
+        steps = elle.closure_steps
+        monkeypatch.setattr(elle, "closure_steps", lambda n: steps(n) - 1)
+        assert _parts(elle.device_sccs(g)) != [list(range(n))]
+
+    def test_padding_adds_no_component(self):
+        # an acyclic graph padded from 1,000 to 1,024 nodes has none,
+        # and ids that are not 0..n-1 come back as themselves
+        g = Graph()
+        for i in range(1000):
+            g.add(5000 + i, 5000 + (i + 1) % 1000 if i % 100 else 4000, WW)
+        assert elle.node_bucket(len(g)) == 1024
+        assert elle.device_sccs(g) == elle.tarjan_sccs(g) == []
+        g.add(5999, 5000, WW)
+        g.add(5000, 5999, WW)
+        assert _parts(elle.device_sccs(g)) == _parts(elle.tarjan_sccs(g))
+
+    @pytest.mark.parametrize("offset", [0, 7_000])
+    def test_edge_arrays_pad_to_a_bucket_with_dropped_positions(self, offset):
+        base = _random_graph(300, seed=3)
+        g = Graph()
+        for a, bs in reversed(list(base.out.items())):
+            g.add_node(a + offset)
+            for b in bs:
+                g.add(a + offset, b + offset, WW)
+        ids, src, dst = elle.edge_arrays(g)
+        e = sum(len(bs) for bs in g.out.values())
+        assert ids == sorted(g.out)
+        assert len(src) == len(dst) == elle.edge_bucket(e) == 1024
+        assert (src[e:] == elle.node_bucket(300)).all()
+        assert (dst[e:] == elle.node_bucket(300)).all()
+        assert {(ids[s], ids[d]) for s, d in zip(src[:e], dst[:e])} == {
+            (a, b) for a, bs in g.out.items() for b in bs}
+
+    def test_sccs_names_its_engine(self, monkeypatch):
+        g = _random_graph(40, seed=1)
+        comps, engine = elle.sccs(g)
+        assert engine == "host" and comps
+        monkeypatch.setattr(elle, "_DEVICE_SCC_MIN_NODES", 0)
+        dev, engine = elle.sccs(g)
+        assert engine == "device" and _parts(dev) == _parts(comps)
+
+    def test_over_the_ceiling_the_host_answers_and_says_so(self, caplog):
+        n = elle.DEVICE_SCC_MAX_NODES + 1
+        g = Graph()
+        for i in range(n):
+            g.add_node(i)
+        g.add(7, 8, WW)
+        g.add(8, 7, WW)
+        with caplog.at_level("WARNING", logger="jepsen_tpu.elle"):
+            comps, engine = elle.sccs(g)
+        assert engine == "host" and _parts(comps) == [[7, 8]]
+        assert "ceiling" in caplog.text
+        with pytest.raises(ValueError, match="ceiling"):
+            elle.device_sccs(g)
+
+
+# ---------------------------- list-append through both SCC engines
+
+
+def _store_history(seed, n_txns=400, fault=None):
+    """A list-append history from a seeded strict-serializable store:
+    5 processes, each txn applied atomically inside its interval;
+    `fault` "stale" makes one read lag, "info" makes a crashed append
+    apply and be read."""
+    import random
+
+    r = random.Random(seed)
+    with fixed_rand(seed):
+        stream = txn_mod.append_txns({"key-count": 2})
+        mops = [next(stream) for _ in range(n_txns)]
+    free = [0.0] * 5
+    txns = []
+    for i, m in enumerate(mops):
+        p = min(range(5), key=lambda q: free[q])
+        inv = free[p] + r.random() * 0.1
+        comp = inv + 0.05 + r.random()
+        typ = "info" if i % 97 == 50 else "fail" if i % 53 == 7 else "ok"
+        at = r.uniform(inv, comp) if typ == "ok" or (
+            typ == "info" and fault == "info") else None
+        txns.append([p, inv, comp, typ, at, m, None])
+        free[p] = comp
+    state, log = {}, {}
+    for t in sorted((t for t in txns if t[4] is not None),
+                    key=lambda t: t[4]):
+        out = []
+        for f, k, v in t[5]:
+            if f == "append":
+                state.setdefault(k, []).append(v)
+                log.setdefault(k, []).append((t[4], v))
+                out.append([f, k, v])
+            else:
+                out.append([f, k, list(state.get(k, []))])
+        t[6] = out
+    if fault == "stale":
+        for t in txns[n_txns // 2:]:
+            if t[3] != "ok" or t[5][0][0] != "r":
+                continue
+            k = t[5][0][1]
+            old = [v for at, v in log.get(k, []) if at <= t[1] - 3.0]
+            if old != t[6][0][2]:
+                t[6][0] = ["r", k, old]
+                break
+    ops = []
+    for when, done, t in sorted([(t[1], 0, i) for i, t in enumerate(txns)]
+                                + [(t[2], 1, i)
+                                   for i, t in enumerate(txns)]):
+        p, _, _, typ, _, m, reads = txns[t]
+        ops.append({"type": typ if done else "invoke", "process": p,
+                    "f": "txn",
+                    "value": reads if done and typ == "ok" else
+                    [[f, k, None if f == "r" else v] for f, k, v in m]})
+    return H(ops)
+
+
+def _answer(r):
+    return (r["valid?"], r["anomaly-types"],
+            {t: len(cs) for t, cs in r["anomalies"].items()})
+
+
+def _engine(monkeypatch, engine):
+    """Steers `elle.sccs` to one engine at any graph size."""
+    monkeypatch.setattr(elle, "_DEVICE_SCC_MIN_NODES",
+                        0 if engine == "device" else 10 ** 9)
+
+
+class TestListAppendEngines:
+    @pytest.mark.parametrize("fault,seed", [(None, 1), (None, 2),
+                                            ("stale", 3), ("stale", 4),
+                                            ("info", 5)])
+    def test_device_and_host_engines_agree(self, fault, seed, monkeypatch):
+        h = _store_history(seed, fault=fault)
+        opts = {"anomalies": ["G1", "G2"], "additional-graphs": ["realtime"]}
+        _engine(monkeypatch, "device")
+        dev = list_append.check(opts, h)
+        _engine(monkeypatch, "host")
+        host = list_append.check(opts, h)
+        assert (dev["scc"], host["scc"]) == ("device", "host")
+        assert _answer(dev) == _answer(host)
+        if fault == "stale":
+            assert dev["anomaly-types"] == ["G-single"]
+        else:
+            assert dev["valid?"] is True
+
+    def test_result_names_the_engine(self, monkeypatch):
+        h = _store_history(6, n_txns=60)
+        assert list_append.check(None, h)["scc"] == "host"
+        _engine(monkeypatch, "device")
+        assert cycle_wl.AppendChecker().check({}, h)["scc"] == "device"
+
+    def test_over_the_ceiling_the_result_names_the_host(self, monkeypatch,
+                                                       caplog):
+        h = _store_history(7, n_txns=60)
+        monkeypatch.setattr(elle, "_DEVICE_SCC_MIN_NODES", 4)
+        monkeypatch.setattr(elle, "DEVICE_SCC_MAX_NODES", 16)
+        with caplog.at_level("WARNING", logger="jepsen_tpu.elle"):
+            r = list_append.check(None, h)
+        assert r["scc"] == "host" and "ceiling" in caplog.text
+
+    def test_spans_and_counters(self, monkeypatch):
+        from jepsen_tpu import obs
+
+        h = _store_history(8, n_txns=200, fault="stale")
+        _engine(monkeypatch, "device")
+        before = obs.registry().snapshot()
+        obs.configure(on=True)
+        try:
+            list_append.check({"additional-graphs": ["realtime"]}, h)
+            spans = obs.tracer().drain()
+        finally:
+            obs.reset()
+        by = {s.name: s for s in spans}
+        root = by["elle.check"]
+        for name in ("elle.scan", "elle.graph", "elle.sccs", "elle.cycles"):
+            assert by[name].parent == root.sid
+        for name in ("elle.sccs.place", "elle.sccs.device",
+                     "elle.sccs.fetch"):
+            assert by[name].parent == by["elle.sccs"].sid
+        moved = obs.registry().delta(before)
+        n_pad = elle.node_bucket(len(txn_mod.ok_txns(h)))
+        assert moved["elle.scc.closure_calls"]["value"] == 1
+        assert moved["elle.scc.padded_nodes"]["value"] == n_pad
+        assert moved["elle.scc.closure_flops"]["value"] == (
+            elle.closure_steps(n_pad) * 2 * n_pad ** 3)
+        assert moved["elle.nodes"]["value"] == len(txn_mod.ok_txns(h))
+        assert moved["elle.edges"]["value"] > 0
