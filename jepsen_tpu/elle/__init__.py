@@ -23,11 +23,14 @@ the host for small graphs, and — the TPU path — boolean transitive
 closure by repeated squaring of the adjacency matrix on the MXU
 (`jnp.dot` over bfloat16 lifts reachability onto the systolic array;
 SCC = R & R.T), which turns the irregular graph walk into dense matmuls
-for histories with thousands of transactions. The device path pads the
-node and edge counts to buckets (one compiled program per bucket),
-scatters int32 edge arrays into the adjacency on the device and fetches
-one component label per node; above `DEVICE_SCC_MAX_NODES` it refuses,
-and `sccs` answers on the host and names that engine.
+for histories with thousands of transactions. A big graph is built as
+typed int arrays (`ArrayGraph`), and the dict form is made only for
+the code that walks it: an SCC's members, or the host's Tarjan. The
+device path pads the node and edge counts to buckets (one compiled
+program per bucket), scatters int32 edge arrays into the adjacency on
+the device and fetches one component label per node; above
+`DEVICE_SCC_MAX_NODES` it refuses, and `sccs` answers on the host and
+names that engine.
 """
 
 from __future__ import annotations
@@ -36,7 +39,7 @@ import itertools
 import logging
 import math
 from collections import deque
-from typing import Callable, Dict, List, Optional, Sequence, Set, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Set, Tuple, Union
 
 from jepsen_tpu import obs
 
@@ -104,8 +107,96 @@ class Graph:
                     g.add(a, b, t)
         return g
 
+    def edge_count(self) -> int:
+        """Distinct (a, b) pairs with an edge."""
+        return sum(len(bs) for bs in self.out.values())
+
     def __len__(self):
         return len(self.out)
+
+
+#: an edge type's bit in `ArrayGraph.types`
+TYPE_BITS = {WW: 1, WR: 2, RW: 4, RT: 8, PROC: 16}
+#: bit mask -> the edge types it holds
+_TYPES_OF = [frozenset(t for t, bit in TYPE_BITS.items() if mask & bit)
+             for mask in range(1 << len(TYPE_BITS))]
+
+
+class ArrayGraph:
+    """Typed edges over the nodes 0..n-1 as int arrays: the distinct
+    (src, dst) pairs, self-loops dropped as `Graph.add` drops them, and
+    `types`, each pair's bit mask over `TYPE_BITS`. It is what a big
+    graph is built as; `subgraph` and `to_graph` give the dict form to
+    the code that walks one."""
+
+    def __init__(self, n: int, src, dst, types):
+        self.n, self.src, self.dst, self.types = n, src, dst, types
+
+    @classmethod
+    def from_edges(cls, n: int, parts) -> "ArrayGraph":
+        """From `parts`, an iterable of (src, dst, type): two int
+        sequences of one length and the edge type they all have. A pair
+        given more than once keeps every type it was given."""
+        import numpy as np
+
+        parts = list(parts)
+        src = np.concatenate([np.asarray(s, np.int64) for s, _, _ in parts])
+        dst = np.concatenate([np.asarray(d, np.int64) for _, d, _ in parts])
+        bits = np.concatenate([np.full(len(s), TYPE_BITS[t], np.uint8)
+                               for s, _, t in parts])
+        keep = src != dst
+        pair = src[keep] * n + dst[keep]
+        order = np.argsort(pair, kind="stable")
+        pair, bits = pair[order], bits[keep][order]
+        starts = np.flatnonzero(np.diff(pair, prepend=-1))
+        pair = pair[starts]
+        return cls(n, pair // n, pair % n,
+                   np.bitwise_or.reduceat(bits, starts))
+
+    def __len__(self):
+        return self.n
+
+    def edge_count(self) -> int:
+        """Distinct (src, dst) pairs with an edge."""
+        return len(self.src)
+
+    def subgraph(self, nodes) -> Graph:
+        """The dict form induced on `nodes`: each of them, and every
+        edge between two of them with its types."""
+        import numpy as np
+
+        members = np.zeros(self.n, bool)
+        members[np.fromiter(nodes, np.int64)] = True
+        return self._dict_form(np.flatnonzero(members),
+                               members[self.src] & members[self.dst])
+
+    def to_graph(self) -> Graph:
+        """The whole dict form: every node and every edge."""
+        import numpy as np
+
+        return self._dict_form(range(self.n), np.ones(len(self.src), bool))
+
+    def _dict_form(self, nodes, sel) -> Graph:
+        g = Graph()
+        out = g.out
+        for a in nodes:
+            out[int(a)] = {}
+        for a, b, m in zip(self.src[sel].tolist(), self.dst[sel].tolist(),
+                           self.types[sel].tolist()):
+            out[a][b] = set(_TYPES_OF[m])
+        return g
+
+
+def expand_ranges(lo, hi):
+    """(rows, cols): for each i, the positions lo[i]..hi[i]-1 in cols,
+    with i repeated as often in rows: the ranges laid end to end."""
+    import numpy as np
+
+    counts = np.maximum(np.asarray(hi) - np.asarray(lo), 0)
+    rows = np.repeat(np.arange(len(counts)), counts)
+    starts = np.cumsum(counts) - counts
+    cols = np.arange(int(counts.sum())) - starts[rows] + np.asarray(lo)[rows]
+    return rows, cols
 
 
 # ------------------------------------------------------------------- SCC
@@ -227,27 +318,35 @@ def closure_flops(n_pad: int) -> int:
     return closure_steps(n_pad) * 2 * n_pad ** 3
 
 
-def edge_arrays(g: Graph):
+def edge_arrays(g: Union[Graph, ArrayGraph]):
     """(ids, src, dst): the graph's node ids in sorted order and its
     edges as int32 positions into them, padded to `edge_bucket` with
-    the out-of-range position `node_bucket(len(ids))`."""
+    the out-of-range position `node_bucket(len(ids))`. An `ArrayGraph`'s
+    ids are its positions already."""
     import numpy as np
 
-    ids = np.array(sorted(g.out), dtype=np.int64)
+    if isinstance(g, ArrayGraph):
+        ids, heads, tails = list(range(g.n)), g.src, g.dst
+    else:
+        ids = np.array(sorted(g.out), dtype=np.int64)
+        fan = np.fromiter(map(len, g.out.values()), np.int64,
+                          count=len(g.out))
+        heads = np.repeat(np.fromiter(g.out, np.int64, count=len(g.out)),
+                          fan)
+        tails = np.fromiter(itertools.chain.from_iterable(g.out.values()),
+                            np.int64, count=int(fan.sum()))
+        heads, tails = np.searchsorted(ids, heads), np.searchsorted(ids, tails)
+        ids = ids.tolist()
     n_pad = node_bucket(len(ids))
-    fan = np.fromiter(map(len, g.out.values()), np.int64, count=len(g.out))
-    e = int(fan.sum())
-    heads = np.repeat(np.fromiter(g.out, np.int64, count=len(g.out)), fan)
-    tails = np.fromiter(itertools.chain.from_iterable(g.out.values()),
-                        np.int64, count=e)
+    e = len(heads)
     src = np.full(edge_bucket(e), n_pad, dtype=np.int32)
     dst = np.full(edge_bucket(e), n_pad, dtype=np.int32)
-    src[:e] = np.searchsorted(ids, heads)
-    dst[:e] = np.searchsorted(ids, tails)
-    return ids.tolist(), src, dst
+    src[:e] = heads
+    dst[:e] = tails
+    return ids, src, dst
 
 
-def device_sccs(g: Graph) -> List[List[int]]:
+def device_sccs(g: Union[Graph, ArrayGraph]) -> List[List[int]]:
     """SCCs (more than one node) via the MXU transitive closure: the
     edge arrays go to the device, the adjacency is built there at the
     padded node count, closed by ceil(log2 n_pad) squarings, and only
@@ -283,21 +382,26 @@ def device_sccs(g: Graph) -> List[List[int]]:
             for comp in np.split(order, cuts) if len(comp) > 1]
 
 
-def sccs(g: Graph) -> Tuple[List[List[int]], str]:
+def sccs(g: Union[Graph, ArrayGraph]) -> Tuple[List[List[int]], str]:
     """(SCCs with more than one node, the engine that found them): the
     device closure from `_DEVICE_SCC_MIN_NODES` nodes up to
-    `DEVICE_SCC_MAX_NODES`, Tarjan on the host otherwise. Over the
-    ceiling it logs why the host answers."""
+    `DEVICE_SCC_MAX_NODES`, Tarjan on the host otherwise (over an
+    `ArrayGraph`'s dict form). Over the ceiling it logs why the host
+    answers."""
     n = len(g)
     obs.counter("elle.nodes").inc(n)
-    obs.counter("elle.edges").inc(sum(len(bs) for bs in g.out.values()))
+    obs.counter("elle.edges").inc(g.edge_count())
     engine = DEVICE if n >= _DEVICE_SCC_MIN_NODES else HOST
     if engine == DEVICE and n > DEVICE_SCC_MAX_NODES:
         log.warning("elle: %d nodes are over the device closure's ceiling "
                     "of %d; SCCs found on the host", n, DEVICE_SCC_MAX_NODES)
         engine = HOST
     with obs.span("elle.sccs", engine=engine, nodes=n):
-        comps = device_sccs(g) if engine == DEVICE else tarjan_sccs(g)
+        if engine == DEVICE:
+            comps = device_sccs(g)
+        else:
+            comps = tarjan_sccs(g.to_graph() if isinstance(g, ArrayGraph)
+                                else g)
     return comps, engine
 
 
@@ -442,32 +546,36 @@ def check(analyzer: Callable, history) -> Dict:
 # ------------------------------------------------- generic graph builders
 
 
-def realtime_graph(oks: List[dict]) -> Graph:
-    """rt edges: T1's completion precedes T2's invocation. Uses the
-    reduced form: edge only from each txn to the txns invoked after it and
-    before any later completion (transitively implied edges dropped)."""
-    import bisect
+def realtime_edges(oks: List[dict]):
+    """rt edges as (src, dst) int arrays of `_id`s: T1's completion
+    precedes T2's invocation. Uses the reduced form: edge only from each
+    txn to the txns invoked after it and before any later completion
+    (transitively implied edges dropped). oks carry "_invoke_index",
+    "_complete_index" and "_id" annotations."""
+    import numpy as np
 
+    ids = np.array([t["_id"] for t in oks], np.int64)
+    inv = np.array([t["_invoke_index"] for t in oks], np.int64)
+    comp = np.array([t["_complete_index"] for t in oks], np.int64)
+    order = np.argsort(inv, kind="stable")
+    invs = inv[order]
+    # suffix_min[i] = min complete index among the txns invoked i-th on
+    suffix_min = np.minimum.accumulate(comp[order][::-1])[::-1]
+    lo = np.searchsorted(invs, comp, side="right")
+    has = lo < len(oks)
+    hi = np.zeros_like(lo)
+    hi[has] = np.searchsorted(invs, suffix_min[lo[has]], side="right")
+    rows, cols = expand_ranges(lo, hi)
+    return ids[rows], ids[order][cols]
+
+
+def realtime_graph(oks: List[dict]) -> Graph:
+    """`realtime_edges` as a `Graph` over every txn."""
     g = Graph()
     for t in oks:
         g.add_node(t["_id"])
-    # oks carry "_invoke_index"/"_complete_index"/"_id" annotations.
-    starts = sorted(oks, key=lambda o: o["_invoke_index"])
-    invs = [t["_invoke_index"] for t in starts]
-    # suffix_min[i] = min complete index among starts[i:]
-    suffix_min = [0] * (len(starts) + 1)
-    suffix_min[len(starts)] = float("inf")
-    for i in range(len(starts) - 1, -1, -1):
-        suffix_min[i] = min(starts[i]["_complete_index"], suffix_min[i + 1])
-    for t1 in oks:
-        i = bisect.bisect_right(invs, t1["_complete_index"])
-        if i >= len(starts):
-            continue
-        horizon = suffix_min[i]
-        for j in range(i, len(starts)):
-            if invs[j] > horizon:
-                break
-            g.add(t1["_id"], starts[j]["_id"], RT)
+    for a, b in zip(*(e.tolist() for e in realtime_edges(oks))):
+        g.add(a, b, RT)
     return g
 
 

@@ -17,7 +17,8 @@ from __future__ import annotations
 from typing import Dict, List, Optional, Set, Tuple
 
 from jepsen_tpu import elle, obs
-from jepsen_tpu.elle import Graph, RW, WR, WW, txn as txn_mod
+from jepsen_tpu.elle import (ArrayGraph, PROC, RT, RW, WR, WW,
+                              txn as txn_mod)
 
 DEFAULT_ANOMALIES = ["G1", "G2"]
 
@@ -113,45 +114,107 @@ def _key_orders(oks: List[dict]) -> Tuple[Dict, List[dict]]:
 # ------------------------------------------------------- graph building
 
 
-def graph(oks: List[dict]) -> Tuple[Graph, Dict, Dict, List[dict]]:
-    """Build the ww/wr/rw dependency graph. Returns
-    (graph, appender-map key->v->txn-id, orders, incompatible-order cases)."""
-    g = Graph()
+def graph(oks: List[dict], additional=()) -> Tuple[ArrayGraph, List[dict]]:
+    """The ww/wr/rw dependency graph over the txns' `_id`s, with the
+    `additional` graphs' edges ("realtime", "process") merged in.
+    Returns (graph, incompatible-order cases).
+
+    ww links consecutive appends of a key's version order; wr the
+    appender of a read's last value to the reader. rw: the observed list
+    is a prefix of the key's final append order, so every committed
+    append NOT in it happened after the read: reader --rw--> its
+    appender. That covers appends whose exact position is unknown (two
+    txns that both read [] and appended: mutual rw, no later read
+    needed). Of two appends of one (k, v), the later txn is the
+    appender."""
+    import numpy as np
+
+    appender: Dict = {}                   # k -> v -> txn, the last wins
+    r_t, r_k, r_v = [], [], []            # the reads that returned a list
     for o in oks:
-        g.add_node(o["_id"])
-    appender: Dict[int, Dict] = {}
-    for o in oks:
+        t = o["_id"]
         for f, k, v in o.get("value") or []:
             if f == "append":
-                appender.setdefault(k, {})[v] = o["_id"]
+                w = appender.get(k)
+                if w is None:
+                    w = appender[k] = {}
+                w[v] = t
+            elif f == "r" and v is not None:
+                r_t.append(t)
+                r_k.append(k)
+                r_v.append(v)
     orders, incompat = _key_orders(oks)
+    bad_keys = {c["key"] for c in incompat}
 
+    # ww: consecutive appends in the version order
+    ww_src, ww_dst = [], []
     for k, order in orders.items():
         writer = appender.get(k, {})
-        # ww: consecutive appends in the version order
-        for v1, v2 in zip(order, order[1:]):
-            a, b = writer.get(v1), writer.get(v2)
+        ws = [writer.get(v) for v in order]
+        for a, b in zip(ws, ws[1:]):
             if a is not None and b is not None:
-                g.add(a, b, WW)
-    # wr + rw per read. The observed list is a prefix of the key's final
-    # append order, so every committed append NOT in the observed list
-    # happened after the read: reader --rw--> its appender. This covers
-    # appends whose exact position is unknown (e.g. two txns that both
-    # read [] and appended — mutual rw, no later read needed).
-    for o in oks:
-        for f, k, rv in o.get("value") or []:
-            if f != "r" or rv is None:
-                continue
-            got = list(rv)
-            if got:
-                w = appender.get(k, {}).get(got[-1])
-                if w is not None and w != o["_id"]:
-                    g.add(w, o["_id"], WR)
-            got_set = set(got)
-            for v, w2 in appender.get(k, {}).items():
-                if v not in got_set and w2 != o["_id"]:
-                    g.add(o["_id"], w2, RW)
-    return g, appender, orders, incompat
+                ww_src.append(a)
+                ww_dst.append(b)
+    # wr: the appender of each read's last value
+    wr_src, wr_dst = [], []
+    for t, k, v in zip(r_t, r_k, r_v):
+        if len(v):
+            w = appender.get(k, {}).get(v[-1])
+            if w is not None:
+                wr_src.append(w)
+                wr_dst.append(t)
+
+    # rw: an append is in a read of k iff its first position in k's
+    # order is below the read's length (an append absent from the order
+    # sits at `absent`), so a read's targets are one suffix of k's
+    # appends sorted by (key, position). A key with no consistent order
+    # takes the set test, read by read.
+    absent = max(map(len, orders.values()), default=0) + 1
+    span = absent + 1
+    key_base: Dict = {}
+    a_sort, a_txn = [], []
+    for k, writer in appender.items():
+        base = key_base[k] = len(key_base) * span
+        first: Dict = {}
+        for i, v in enumerate(orders.get(k, ())):
+            first.setdefault(v, i)
+        for v, w in writer.items():
+            a_sort.append(base + first.get(v, absent))
+            a_txn.append(w)
+    a_sort = np.array(a_sort, np.int64)
+    by_pos = np.argsort(a_sort, kind="stable")
+    a_sort, a_txn = a_sort[by_pos], np.array(a_txn, np.int64)[by_pos]
+    r_txn, r_base, r_len = [], [], []
+    set_src, set_dst = [], []
+    set_reads = 0
+    for t, k, v in zip(r_t, r_k, r_v):
+        if k in bad_keys:
+            set_reads += 1
+            got = set(v)
+            for x, w in appender.get(k, {}).items():
+                if x not in got:
+                    set_src.append(t)
+                    set_dst.append(w)
+        elif k in key_base:
+            r_txn.append(t)
+            r_base.append(key_base[k])
+            r_len.append(len(v))
+    obs.counter("elle.graph.set_path_reads").inc(set_reads)
+    r_base = np.array(r_base, np.int64)
+    lo = np.searchsorted(a_sort, r_base + np.array(r_len, np.int64))
+    hi = np.searchsorted(a_sort, r_base + span)
+    rows, cols = elle.expand_ranges(lo, hi)
+
+    parts = [(ww_src, ww_dst, WW), (wr_src, wr_dst, WR),
+             (np.array(r_txn, np.int64)[rows], a_txn[cols], RW),
+             (set_src, set_dst, RW)]
+    if "realtime" in additional:
+        parts.append((*elle.realtime_edges(oks), RT))
+    if "process" in additional:
+        pg = elle.process_graph(oks)
+        parts.append(([a for a, bs in pg.out.items() for _ in bs],
+                      [b for bs in pg.out.values() for b in bs], PROC))
+    return ArrayGraph.from_edges(len(oks), parts), incompat
 
 
 # ---------------------------------------------------------------- check
@@ -179,18 +242,14 @@ def _check(o: Dict, history) -> Dict:
         _read_anomalies(history, oks, wanted, anomalies)
 
     with obs.span("elle.graph"):
-        g, _appender, _orders, incompat = graph(oks)
+        g, incompat = graph(oks, o.get("additional-graphs") or ())
         if incompat:
             anomalies["incompatible-order"] = incompat
-        extra = o.get("additional-graphs") or []
-        if "realtime" in extra:
-            g.merge(elle.realtime_graph(oks))
-        if "process" in extra:
-            g.merge(elle.process_graph(oks))
 
     comps, engine = elle.sccs(g)
     with obs.span("elle.cycles", sccs=len(comps)):
-        cyc = elle.classify(g, comps, by_id=by_id)
+        members = g.subgraph(v for comp in comps for v in comp)
+        cyc = elle.classify(members, comps, by_id=by_id)
     for name, cases in cyc.items():
         if name in wanted:
             anomalies[name] = cases

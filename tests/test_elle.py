@@ -514,3 +514,167 @@ class TestListAppendEngines:
             elle.closure_steps(n_pad) * 2 * n_pad ** 3)
         assert moved["elle.nodes"]["value"] == len(txn_mod.ok_txns(h))
         assert moved["elle.edges"]["value"] > 0
+
+
+# ------------------------------- the array graph against the dict builder
+
+
+def _dict_graph(oks, additional=()):
+    """The dict-of-sets builder the array graph replaced, kept as the
+    oracle: ww/wr/rw loops over the appender map, the bisect realtime
+    loop, and the process graph merged in."""
+    import bisect
+
+    g = Graph()
+    for o in oks:
+        g.add_node(o["_id"])
+    appender = {}
+    for o in oks:
+        for f, k, v in o.get("value") or []:
+            if f == "append":
+                appender.setdefault(k, {})[v] = o["_id"]
+    orders, _ = list_append._key_orders(oks)
+    for k, order in orders.items():
+        writer = appender.get(k, {})
+        for v1, v2 in zip(order, order[1:]):
+            a, b = writer.get(v1), writer.get(v2)
+            if a is not None and b is not None:
+                g.add(a, b, WW)
+    for o in oks:
+        for f, k, rv in o.get("value") or []:
+            if f != "r" or rv is None:
+                continue
+            got = list(rv)
+            if got:
+                w = appender.get(k, {}).get(got[-1])
+                if w is not None and w != o["_id"]:
+                    g.add(w, o["_id"], WR)
+            got_set = set(got)
+            for v, w2 in appender.get(k, {}).items():
+                if v not in got_set and w2 != o["_id"]:
+                    g.add(o["_id"], w2, RW)
+    if "realtime" in additional:
+        starts = sorted(oks, key=lambda o: o["_invoke_index"])
+        invs = [t["_invoke_index"] for t in starts]
+        suffix_min = [0] * (len(starts) + 1)
+        suffix_min[len(starts)] = float("inf")
+        for i in range(len(starts) - 1, -1, -1):
+            suffix_min[i] = min(starts[i]["_complete_index"],
+                                suffix_min[i + 1])
+        for t1 in oks:
+            i = bisect.bisect_right(invs, t1["_complete_index"])
+            if i >= len(starts):
+                continue
+            for j in range(i, len(starts)):
+                if invs[j] > suffix_min[i]:
+                    break
+                g.add(t1["_id"], starts[j]["_id"], elle.RT)
+    if "process" in additional:
+        g.merge(elle.process_graph(oks))
+    return g
+
+
+def _typed(g):
+    """A graph's edges as (src, dst, types), either form."""
+    if isinstance(g, elle.ArrayGraph):
+        return {(a, b, elle._TYPES_OF[m]) for a, b, m in zip(
+            g.src.tolist(), g.dst.tolist(), g.types.tolist())}
+    return {(a, b, frozenset(ts)) for a, bs in g.out.items()
+            for b, ts in bs.items()}
+
+
+def _incompatible_history():
+    # x is read as [1, 2] and as [2, 1]: no order, so its two reads
+    # take the set test; y's order is consistent
+    return H([*txn_pair(0, [["append", "x", 1], ["append", "y", 1]],
+                        [["append", "x", 1], ["append", "y", 1]]),
+              *txn_pair(1, [["append", "x", 2], ["r", "y", None]],
+                        [["append", "x", 2], ["r", "y", [1]]]),
+              *txn_pair(2, [["r", "x", None], ["append", "x", 3]],
+                        [["r", "x", [1, 2]], ["append", "x", 3]]),
+              *txn_pair(3, [["r", "x", None], ["r", "y", None]],
+                        [["r", "x", [2, 1]], ["r", "y", []]])])
+
+
+def _own_read_history():
+    # T0 appends x and reads its own append back: no wr self-loop
+    return H([*txn_pair(0, [["append", "x", 1], ["r", "x", None]],
+                        [["append", "x", 1], ["r", "x", [1]]]),
+              *txn_pair(1, [["r", "x", None], ["append", "x", 2]],
+                        [["r", "x", [1]], ["append", "x", 2]]),
+              *txn_pair(2, [["r", "x", None]], [["r", "x", [1, 2]]])])
+
+
+def _duplicate_append_history():
+    # two txns append (x, 1): the later one is its appender
+    return H([*txn_pair(0, [["append", "x", 1]], [["append", "x", 1]]),
+              *txn_pair(1, [["r", "x", None]], [["r", "x", []]]),
+              *txn_pair(2, [["append", "x", 1], ["append", "y", 5]],
+                        [["append", "x", 1], ["append", "y", 5]]),
+              *txn_pair(3, [["r", "x", None], ["r", "y", None]],
+                        [["r", "x", [1]], ["r", "y", [5]]])])
+
+
+class TestArrayGraph:
+    @pytest.mark.parametrize("case,additional,set_reads", [
+        ("store", ("realtime",), 0),
+        ("stale", ("realtime",), 0),
+        ("info", ("realtime",), 0),
+        ("store", ("realtime", "process"), 0),
+        ("incompatible", ("realtime",), 2),
+        ("own-read", ("realtime",), 0),
+        ("duplicate", (), 0),
+    ])
+    def test_same_typed_edges_as_the_dict_builder(self, case, additional,
+                                                  set_reads):
+        from jepsen_tpu import obs
+
+        h = {"store": lambda: _store_history(1),
+             "stale": lambda: _store_history(3, fault="stale"),
+             "info": lambda: _store_history(5, fault="info"),
+             "incompatible": _incompatible_history,
+             "own-read": _own_read_history,
+             "duplicate": _duplicate_append_history}[case]()
+        oks = txn_mod.ok_txns(h)
+        before = obs.registry().snapshot()
+        g, incompat = list_append.graph(oks, additional)
+        moved = obs.registry().delta(before)
+        want = _dict_graph(oks, additional)
+        assert len(g) == len(oks) == len(want)
+        assert g.edge_count() == want.edge_count() > 0
+        assert _typed(g) == _typed(want)
+        assert bool(incompat) == (case == "incompatible")
+        assert moved.get("elle.graph.set_path_reads",
+                         {"value": 0})["value"] == set_reads
+        if "realtime" in additional:
+            assert elle.realtime_graph(oks).out == \
+                _dict_graph(oks, ("realtime",)).restrict({elle.RT}).out
+        if case == "duplicate":
+            assert (0, 1, frozenset({WR})) not in _typed(g)
+            assert (2, 3, frozenset({WR})) in _typed(g)
+
+    def test_dict_forms(self):
+        h = _store_history(3, fault="stale")
+        oks = txn_mod.ok_txns(h)
+        g, _ = list_append.graph(oks, ("realtime",))
+        want = _dict_graph(oks, ("realtime",))
+        assert g.to_graph().out == want.out
+        comps = elle.tarjan_sccs(want)
+        members = {v for c in comps for v in c}
+        assert members
+        sub = g.subgraph(members)
+        assert set(sub.out) == members
+        assert sub.out == {a: {b: ts for b, ts in want.out[a].items()
+                               if b in members} for a in members}
+        # the same classes; which cycle a search finds may differ
+        assert {t: len(cs) for t, cs in elle.classify(sub, comps).items()} \
+            == {t: len(cs) for t, cs in elle.classify(want, comps).items()}
+        ids, src, dst = elle.edge_arrays(g)
+        ids2, src2, dst2 = elle.edge_arrays(g.to_graph())
+        assert ids == ids2 == list(range(len(oks)))
+        assert (src == src2).all() and (dst == dst2).all()
+
+    def test_an_empty_graph(self):
+        g, incompat = list_append.graph([], ("realtime",))
+        assert (len(g), g.edge_count(), incompat) == (0, 0, [])
+        assert g.to_graph().out == {} and g.subgraph([]).out == {}
