@@ -170,49 +170,6 @@ def emit_search_stats(section: str, results, extra=None):
     emit(line)
 
 
-def emit_steal_advisory(section: str):
-    """The flag-gated elastic-scheduling advisory line: emitted ONLY
-    under JEPSEN_TPU_STEAL=1, so the default bench schema is
-    byte-identical (gating pinned in test_bench.py). Runs the
-    recorded forced-skew shape (parallel.elastic.forced_skew_histories
-    — heavy ladder-climbing keys statically pinned onto the first
-    devices) through the SAME round executor with stealing off then
-    on, and reports the wall-clock win plus the per-device busy/idle
-    accounting both arms observed — the chip-evidence row the
-    JEPSEN_TPU_STEAL flag flip needs."""
-    if not envflags.env_bool("JEPSEN_TPU_STEAL", default=False):
-        return
-    import jax
-    import numpy as np
-    from jax.sharding import Mesh
-    from jepsen_tpu.parallel import elastic, encode as enc_mod
-    model, hs = elastic.forced_skew_histories()
-    pre = [enc_mod.encode(model, h) for h in hs]
-    mesh = Mesh(np.array(jax.devices()), ("key",))
-    with obs.timer("bench.steal_ab", keys=len(pre)):
-        ab = elastic.steal_ab(model, pre, mesh)
-    b_steal = ab["steal"][0]
-    b_static = ab["static"][0]
-    emit({"metric": f"{section} elastic steal A/B (advisory, "
-                    f"JEPSEN_TPU_STEAL)",
-          "value": ab["steal_speedup"], "unit": "x speedup",
-          "static_secs": ab["static_secs"],
-          "steal_secs": ab["steal_secs"],
-          "verdicts_identical": ab["verdicts_identical"],
-          "keys": len(pre), "rounds": b_steal.get("rounds"),
-          "keys_stolen": b_steal.get("steals"),
-          "busy_frac_static": b_static.get("busy_frac"),
-          "busy_frac_steal": b_steal.get("busy_frac"),
-          "per_device_busy_static": b_static.get("per_device_busy"),
-          "per_device_busy_steal": b_steal.get("per_device_busy"),
-          "note": "forced-skew shape: heavy capacity-ladder keys "
-                  "pinned on the first devices by the static "
-                  "placement; stealing migrates the pending backlog "
-                  "wide (docs/performance.md 'Elastic scheduling'); "
-                  "absent without the flag — default schema "
-                  "unchanged"})
-
-
 def emit_reshard_advisory(e, mesh, cap0: int, max_cap: int,
                           static_r: dict, static_secs: float):
     """The flag-gated re-shard ladder advisory (JEPSEN_TPU_RESHARD=1
@@ -423,7 +380,6 @@ def sec_multikey():
                   "second pass over the same histories, zero "
                   "re-encodes; buckets carry the per-bucket "
                   "encode/transfer/device split"})
-    emit_steal_advisory(f"multi-key {N_KEYS}x{OPS_PER_KEY}-op")
 
 
 def sec_adv(L: int, host_deadline: float, skip_host: bool,

@@ -232,22 +232,6 @@ def env_path(name: str, what: str = "path") -> Optional[str]:
 #                            check_batch through the pipelined
 #                            executor (parallel.pipeline); opt-in
 #                            until bench records a win
-#   JEPSEN_TPU_STEAL         env_bool    parallel.engine — skew-driven
-#                            key work-stealing in the multi-key
-#                            executors (parallel.elastic): buckets
-#                            dispatch in device-aligned rounds and a
-#                            scheduler migrates pending keys between
-#                            per-device queues from observed
-#                            search-stats/cost signals; results
-#                            bit-identical to the static placement
-#                            (parity-pinned); opt-in until
-#                            tools/perf_ab.py's steal arm records the
-#                            win
-#   JEPSEN_TPU_STEAL_ROUND   env_int     parallel.elastic — keys per
-#                            device per dispatch round of the stealing
-#                            executor (default 1, min 1): smaller =
-#                            more observation/rebalance points, larger
-#                            = fewer, bigger device programs
 #   JEPSEN_TPU_RESHARD       env_bool    parallel.engine/sharded —
 #                            re-shard-on-escalation: a sharded search
 #                            (incl. the batch overflow escalation
@@ -497,7 +481,7 @@ def env_path(name: str, what: str = "path") -> Optional[str]:
 #   JEPSEN_TPU_LEDGER        env_path    obs.ledger — the decision
 #                            ledger: append one durable JSONL evidence
 #                            record per device dispatch / escalation /
-#                            reshard / steal / serve publish (shape
+#                            reshard / serve publish (shape
 #                            fingerprint, strategy vector, secs,
 #                            stats digest, outcome) into bounded
 #                            segments under the given dir ("1" =
@@ -537,7 +521,7 @@ def env_path(name: str, what: str = "path") -> Optional[str]:
 #   JEPSEN_TPU_AUTO          env_bool    parallel.planner — the self-
 #                            tuning strategy planner: per slot-window
 #                            bucket, pick the strategy vector (dedupe,
-#                            pallas closure, pack, pipeline, steal)
+#                            pallas closure, pack, pipeline)
 #                            from a per-shape decision table seeded by
 #                            the `jepsen report --plan` advisor join
 #                            and updated online (EWMA per

@@ -303,19 +303,15 @@ class IndependentChecker(Checker):
                  pipeline: Optional[bool] = None,
                  dedupe: Optional[str] = None,
                  search_stats: Optional[bool] = None,
-                 steal: Optional[bool] = None,
                  reshard: Optional[bool] = None):
         self.checker = checker
         self.batch_device = batch_device
         self.pipeline = pipeline
         self.dedupe = dedupe
         self.search_stats = search_stats
-        # elastic scheduling knobs (None = the JEPSEN_TPU_STEAL /
-        # JEPSEN_TPU_RESHARD flags): skew-driven key work-stealing in
-        # the batched dispatch, device-recruiting escalation for
-        # overflow keys — results identical either way
-        # (docs/performance.md "Elastic scheduling")
-        self.steal = steal
+        # device-recruiting escalation for overflow keys (None = the
+        # JEPSEN_TPU_RESHARD flag) — results identical either way
+        # (docs/performance.md "Elastic re-sharding")
         self.reshard = reshard
 
     def check(self, test, history, opts=None):
@@ -451,7 +447,6 @@ class IndependentChecker(Checker):
                                         mesh=mesh, pipeline=self.pipeline,
                                         dedupe=self.dedupe,
                                         search_stats=self.search_stats,
-                                        steal=self.steal,
                                         reshard=self.reshard)
             return rs, None
         except EncodeError as err:
@@ -516,8 +511,7 @@ def checker(c: Checker, batch_device: bool = True,
             pipeline: Optional[bool] = None,
             dedupe: Optional[str] = None,
             search_stats: Optional[bool] = None,
-            steal: Optional[bool] = None,
             reshard: Optional[bool] = None) -> IndependentChecker:
     return IndependentChecker(c, batch_device, pipeline=pipeline,
                               dedupe=dedupe, search_stats=search_stats,
-                              steal=steal, reshard=reshard)
+                              reshard=reshard)

@@ -5,14 +5,14 @@ table — the artifact the ``JEPSEN_TPU_AUTO=1`` planner
 (``parallel.planner``) seeds its live decision table from, built here
 as read-only provenance:
 
-  ledger   the decision ledger's dispatch/escalation/reshard/steal
+  ledger   the decision ledger's dispatch/escalation/reshard
            records (``obs.ledger``) — live traffic's shape×strategy
            cells with measured wall secs
   bench    ``bench_results/`` perf_ab JSONL — the recorded A/B
            verdicts per axis: closure (``xla/pallas/fori_secs``),
            dedupe (``sort/hash/hash-pallas/hash-packed_secs``),
-           elastic (``static_secs`` vs ``steal_secs`` /
-           ``reshard_secs``), plus the flip-rule verdict records
+           elastic (``static_secs`` vs ``reshard_secs``), plus the
+           flip-rule verdict records
   gates    ``sparse_kernels.gate_coverage`` records riding the same
            bench JSONL — which kernel would run per layout, chip-free
 
@@ -43,7 +43,7 @@ from jepsen_tpu.obs import ledger as _ledger
 #: strategy-axis vocabulary of the perf_ab ``{variant}_secs`` keys
 CLOSURE_VARIANTS = ("xla", "pallas", "fori")
 DEDUPE_VARIANTS = ("sort", "hash", "hash-pallas", "hash-packed")
-ELASTIC_ARMS = ("steal", "reshard")
+ELASTIC_ARMS = ("reshard",)
 
 PLAN_VERSION = 1
 
@@ -102,7 +102,7 @@ def _axis_wins(records: List[dict],
 
 
 def _elastic_verdicts(records: List[dict]) -> Dict[str, dict]:
-    """steal/reshard vs the static placement: per arm, on how many
+    """reshard vs the static placement: per arm, on how many
     shapes the arm beat ``static_secs``."""
     out: Dict[str, dict] = {}
     for arm in ELASTIC_ARMS:

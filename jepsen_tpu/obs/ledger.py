@@ -2,22 +2,21 @@
 records for every device-dispatch and strategy decision the engines
 make (``JEPSEN_TPU_LEDGER``).
 
-Why it exists (ROADMAP item 2): the engine now has ~6 orthogonal
+Why it exists (ROADMAP item 2): the engine has five orthogonal
 strategy axes (dedupe sort|hash, fused|tiled|xla closure,
-packed|unpacked, pipeline depth, steal, reshard rung) and all the
-evidence for choosing between them per shape is ephemeral —
-``search_stats`` blocks die with the result dict, registry snapshots
-die with the process, the elastic cost model is in-memory only, and
-the ``bench_results/`` perf_ab verdicts are never joined against live
-traffic. The ledger makes that evidence durable and queryable: one
-compact JSONL record per dispatch (and per escalation / reshard /
-steal / publish decision), carrying
+packed|unpacked, pipeline depth, reshard rung) and all the evidence
+for choosing between them per shape is ephemeral — ``search_stats``
+blocks die with the result dict, registry snapshots die with the
+process, and the ``bench_results/`` perf_ab verdicts are never joined
+against live traffic. The ledger makes that evidence durable and
+queryable: one compact JSONL record per dispatch (and per escalation
+/ reshard / publish decision), carrying
 
     shape       the padded-program fingerprint — event family, N
                 (capacity), R (padded events), C (padded slots),
                 capacity tier, pack layout
     strategy    the vector that actually ran — dedupe, closure kernel,
-                pack, pipeline depth, steal, reshard rung, probe_limit
+                pack, pipeline depth, reshard rung, probe_limit
     secs        wall time between the SAME ``perf_counter`` reads the
                 dispatch spans use (bench splits and ledger rows
                 cannot disagree)

@@ -83,8 +83,7 @@ def device_skew(r: dict):
     """max/mean of the per-device load-factor peaks across the mesh
     (sharded records; falls back to the per-device frontier-width
     peaks) — 1.0 means perfectly balanced, higher means part of the
-    mesh idles while one device's table runs hot: stealable skew the
-    elastic scheduler (JEPSEN_TPU_STEAL) attacks. None for
+    mesh idles while one device's table runs hot. None for
     single-device records."""
     pd = r.get("per-device") or {}
     vals = pd.get("load-factor-peak") or pd.get("width-peak")
@@ -154,8 +153,7 @@ def render_search_report(records: List[dict]) -> str:
     lines.extend(_worst_table(rows, "pad-waste",
                               "Worst keys by pad-row waste"))
     lines.extend(_worst_table(rows, "device-skew",
-                              "Worst keys by per-device skew "
-                              "(stealable imbalance)"))
+                              "Worst keys by per-device skew"))
     if len(lines) == 5 and not agg:   # header only: nothing ranked
         lines.append("(no key exceeded any threshold — no hash load, "
                      "no escalations, no pad waste)")

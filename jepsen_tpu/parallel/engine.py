@@ -2382,9 +2382,7 @@ def check_batch(model, histories, capacity: int = 512,
                 sparse_pallas: Optional[bool] = None,
                 search_stats: Optional[bool] = None,
                 config_pack: Optional[bool] = None,
-                steal: Optional[bool] = None,
-                reshard: Optional[bool] = None,
-                steal_stats: Optional[dict] = None) -> list:
+                reshard: Optional[bool] = None) -> list:
     """Check many per-key histories in one device program per
     slot-window bucket: vmap over the key axis; with a mesh (and K
     divisible by its size) the key axis is sharded across devices —
@@ -2425,17 +2423,9 @@ def check_batch(model, histories, capacity: int = 512,
     fused VMEM frontier kernel (check_encoded's docstring; None = the
     JEPSEN_TPU_SPARSE_PALLAS flag).
 
-    `steal` (None = JEPSEN_TPU_STEAL) routes the batch through the
-    elastic round-based executor (parallel.elastic): keys dispatch in
-    device-aligned rounds and a skew-aware placement loop migrates
-    pending keys between per-device queues from the observed
-    search-stats/cost signal of completed rounds — results
-    bit-identical to the static path (verdict, op/fail-event,
-    max-frontier, capacity, configs-stepped; docs/performance.md
-    "Elastic scheduling"). `steal_stats`, when a dict, receives the
-    scheduler's per-device cost/steal accounting. `reshard` (None =
-    JEPSEN_TPU_RESHARD) makes capacity escalation recruit mesh devices
-    (sharded elastic ladder) instead of only growing tables."""
+    `reshard` (None = JEPSEN_TPU_RESHARD) makes capacity escalation
+    recruit mesh devices (sharded elastic ladder) instead of only
+    growing tables."""
     bucket = _resolve_bucket(bucket)   # fail-fast: before the encode
     pl = _planner.active()
     from time import perf_counter as _pc
@@ -2445,17 +2435,14 @@ def check_batch(model, histories, capacity: int = 512,
         _resolve_dedupe(dedupe)   # fail-fast validation only — with
         # the planner armed the dedupe REQUEST stays raw so each
         # sparse bucket plans its own arm per shape
-        # (_check_batch_sparse); the batch-level axes (executor
-        # choice) are planned here, where they route
+        # (_check_batch_sparse); the batch-level axis (executor
+        # choice) is planned here, where it routes
         dec = pl.decide("batch", type(model).__name__, None,
-                        {"pipeline": pipeline, "steal": steal},
-                        keys=len(histories))
+                        {"pipeline": pipeline}, keys=len(histories))
         if dec is not None:
             pipeline = dec["strategy"].get("pipeline", pipeline)
-            steal = dec["strategy"].get("steal", steal)
         t0_plan = _pc()
     run_pipeline = _resolve_pipeline(pipeline)
-    run_steal = bool(_resolve_steal(steal))
     if run_pipeline:
         from jepsen_tpu.parallel import pipeline as pipe_mod
         res = pipe_mod.check_batch_pipelined(
@@ -2463,30 +2450,8 @@ def check_batch(model, histories, capacity: int = 512,
             max_capacity=max_capacity, mesh=mesh, bucket=bucket,
             cache=cache, stats=pipeline_stats, dedupe=dedupe,
             sparse_pallas=sparse_pallas, search_stats=search_stats,
-            config_pack=config_pack, steal=steal, reshard=reshard,
-            steal_stats=steal_stats)
-    elif run_steal:
-        from jepsen_tpu.parallel import elastic
-        with obs.span("engine.check_batch", keys=len(histories),
-                      bucket=bucket), obs.maybe_jax_profile():
-            with obs.span("engine.encode_batch", keys=len(histories)):
-                pre = [enc_mod.encode(model, h) for h in histories]
-            res = elastic.check_batch_stealing(
-                model, pre, capacity=capacity,
-                max_capacity=max_capacity, mesh=mesh, bucket=bucket,
-                dedupe=dedupe, sparse_pallas=sparse_pallas,
-                search_stats=search_stats, config_pack=config_pack,
-                reshard=reshard, stats=steal_stats)
+            config_pack=config_pack, reshard=reshard)
     else:
-        if steal_stats is not None:
-            # same loud contract as cache/pipeline_stats below: the
-            # static path runs no scheduler and would silently leave
-            # the dict empty while the caller believes stealing was
-            # measured
-            raise ValueError(
-                "check_batch: steal_stats is an elastic-executor "
-                "argument — pass steal=True (or set "
-                "JEPSEN_TPU_STEAL=1) to use it")
         if (cache is not None and cache is not False) \
                 or pipeline_stats is not None:
             # the serial path consults no cache and fills no stats —
@@ -2515,7 +2480,7 @@ def check_batch(model, histories, capacity: int = 512,
                                       reshard=reshard)
     if pl is not None:
         pl.observe("batch", type(model).__name__, None,
-                   {"pipeline": run_pipeline, "steal": run_steal},
+                   {"pipeline": run_pipeline},
                    _pc() - t0_plan)
     return res
 
@@ -2540,20 +2505,11 @@ def _resolve_pipeline(pipeline: Optional[bool]) -> bool:
     return bool(pipeline)
 
 
-def _resolve_steal(steal: Optional[bool]) -> bool:
-    """JEPSEN_TPU_STEAL: skew-driven key work-stealing in the
-    multi-key executors (parallel.elastic). Opt-in until the recorded
-    A/B (tools/perf_ab.py steal arm) flips it — flags do not get to
-    claim speedups."""
-    if steal is None:
-        steal = envflags.env_bool("JEPSEN_TPU_STEAL", default=False)
-    return bool(steal)
-
-
 def _resolve_reshard(reshard: Optional[bool]) -> bool:
     """JEPSEN_TPU_RESHARD: capacity escalation recruits devices
     (parallel.sharded.check_encoded_sharded_elastic) instead of only
-    growing per-device tables. Opt-in, same contract as STEAL."""
+    growing per-device tables. Opt-in until a recorded A/B flips it —
+    flags do not get to claim speedups."""
     if reshard is None:
         reshard = envflags.env_bool("JEPSEN_TPU_RESHARD",
                                     default=False)

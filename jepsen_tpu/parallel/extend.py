@@ -772,18 +772,18 @@ class HistorySession:
                 "history (digest/model drift) — rescanning key %r "
                 "from scratch", self.key)
 
-    # -- elastic migration (the serve layer's work-stealing)
+    # -- device migration (serve.service.steal_key)
 
     def migrate(self, device) -> None:
-        """Re-place the session's device search onto ``device`` — the
-        mid-stream half of elastic key work-stealing
-        (JEPSEN_TPU_STEAL). The canonical host-side FrontierCheckpoint
-        IS the migration primitive: every retained checkpoint stores
-        unsharded numpy rows, so moving a streamed key between devices
-        is pure re-placement — the next scan's ``cp.carry(device)``
-        lands on the new device and resumes bit-identically, exactly
-        as the freeze/thaw eviction path already proves. Keys are
-        independent; no device state moves."""
+        """Re-place the session's device search onto ``device``
+        (serve.service.steal_key). The canonical host-side
+        FrontierCheckpoint IS the migration primitive: every retained
+        checkpoint stores unsharded numpy rows, so moving a streamed
+        key between devices is pure re-placement — the next scan's
+        ``cp.carry(device)`` lands on the new device and resumes
+        bit-identically, exactly as the freeze/thaw eviction path
+        already proves. Keys are independent; no device state
+        moves."""
         if device is self.device:
             return
         self.device = device

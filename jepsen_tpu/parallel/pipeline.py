@@ -446,9 +446,7 @@ def check_batch_pipelined(model, histories, capacity: int = 512,
                           sparse_pallas: Optional[bool] = None,
                           search_stats: Optional[bool] = None,
                           config_pack: Optional[bool] = None,
-                          steal: Optional[bool] = None,
-                          reshard: Optional[bool] = None,
-                          steal_stats: Optional[dict] = None) -> list:
+                          reshard: Optional[bool] = None) -> list:
     """engine.check_batch with the three host/device phases overlapped
     (module docstring). Same arguments and bit-identical results;
     extras:
@@ -477,19 +475,8 @@ def check_batch_pipelined(model, histories, capacity: int = 512,
                 (engine.check_encoded's docstring; None =
                 JEPSEN_TPU_CONFIG_PACK) — bitdense buckets are
                 untouched (the dense bitmap has no row triple to pack)
-    steal       skew-aware chunk scheduling (None = JEPSEN_TPU_STEAL;
-                parallel.elastic): bitdense chunks compose through a
-                KeyScheduler that rebalances pending keys from each
-                drained chunk's observed costs (the bitdense cost
-                signal is the search-stats block, so rebalancing is
-                live when JEPSEN_TPU_SEARCH_STATS is armed), and the
-                sparse tail runs the elastic round executor instead of
-                one monolithic ladder. Results bit-identical; order
-                of dispatch is the only thing that moves.
     reshard     device-recruiting escalation for overflow keys (None
                 = JEPSEN_TPU_RESHARD; engine._escalate_overflow)
-    steal_stats optional dict, filled with the schedulers' per-bucket
-                steal/busy accounting
     """
     bucket = engine._resolve_bucket(bucket)
     if _planner.active() is None:
@@ -505,14 +492,6 @@ def check_batch_pipelined(model, histories, capacity: int = 512,
         engine._resolve_dedupe(dedupe)
         dedupe_label = dedupe if dedupe is not None else "auto"
     search_stats = engine._resolve_search_stats(search_stats)
-    steal = engine._resolve_steal(steal)
-    if steal_stats is not None and not steal:
-        # same loud contract as the serial path's guard: without the
-        # scheduler the dict would stay silently empty while the
-        # caller believes stealing was measured
-        raise ValueError(
-            "check_batch: steal_stats is an elastic-executor argument "
-            "— pass steal=True (or set JEPSEN_TPU_STEAL=1) to use it")
     if stats is None:
         stats = {}
     K = len(histories)
@@ -540,7 +519,7 @@ def check_batch_pipelined(model, histories, capacity: int = 512,
         out = _stream(model, histories, capacity, max_capacity, mesh,
                       bucket, cache, workers, chunk_keys, depth, stats,
                       dedupe, bitdense, sparse_pallas, search_stats,
-                      config_pack, steal, reshard, steal_stats)
+                      config_pack, reshard)
     if c0 is not None:
         c1 = cache.counters()
         stats["cache"] = {k: c1[k] - c0[k] for k in
@@ -559,8 +538,7 @@ def _stream(model, histories, capacity, max_capacity, mesh, bucket,
             cache, workers, chunk_keys, depth, stats, dedupe,
             bitdense, sparse_pallas=None,
             search_stats: bool = False, config_pack=None,
-            steal: bool = False, reshard=None,
-            steal_stats: Optional[dict] = None) -> list:
+            reshard=None) -> list:
     """The executor body (check_batch_pipelined's docstring), under the
     pipeline.run root span. Telemetry it feeds: pipeline.prepare /
     pipeline.encode spans on the pool threads (nested via ctx_runner),
@@ -623,7 +601,6 @@ def _stream(model, histories, capacity, max_capacity, mesh, bucket,
         # ---- phase 3: stream buckets through the double buffer
         pending: deque = deque()
         bstats: list = []
-        scheds: list = []   # (bstat, KeyScheduler) of stealing buckets
 
         def degrade_chunk(chunk_idxs, err, bstat):
             """A failed chunk degrades ONLY ITS KEYS to the host WGL
@@ -640,16 +617,13 @@ def _stream(model, histories, capacity, max_capacity, mesh, bucket,
             bstat["degraded"] = bstat.get("degraded", 0) + len(chunk_idxs)
 
         def drain_one():
-            (chunk_idxs, pb, bstat, chunk_no, t_issue, sched,
-             placement) = pending.popleft()
+            chunk_idxs, pb, bstat, chunk_no, t_issue = pending.popleft()
             try:
                 with obs.span("pipeline.finalize", tier=bstat["tier"],
                               chunk=chunk_no, keys=len(chunk_idxs)):
                     rs = sup.dispatch("pipeline", pb.finalize)
             except sup.DISPATCH_FAILURES as err:
                 degrade_chunk(chunk_idxs, err, bstat)
-                if sched is not None:
-                    sched.observe({}, placement)
                 _depth(len(pending))
                 return
             _depth(len(pending))
@@ -671,14 +645,13 @@ def _stream(model, histories, capacity, max_capacity, mesh, bucket,
                 # one record per drained chunk: the in-flight window
                 # (same clock reads as the device.search span) plus
                 # the pipeline-level strategy the bitdense record
-                # cannot see (depth, steal, chunk sizing)
+                # cannot see (depth, chunk sizing)
                 led.record(
                     "dispatch", engine="pipeline",
                     shape={"family": enc_of(chunk_idxs[0]).step_name,
                            "tier": bstat["tier"]},
                     strategy={"engine": bstat["engine"],
                               "depth": depth,
-                              "steal": sched is not None,
                               "chunk_keys": chunk_keys},
                     secs=round(t_done - t_issue, 6),
                     keys=len(chunk_idxs), chunk=chunk_no,
@@ -690,18 +663,6 @@ def _stream(model, histories, capacity, max_capacity, mesh, bucket,
             bstat["device_wait_secs"] += pb.device_wait_secs
             for i, r in zip(chunk_idxs, rs):
                 out[i] = r
-            if sched is not None:
-                # the stealer's observation point: the drained chunk's
-                # per-key costs rebalance whatever is still queued.
-                # With depth > 1 the feedback lags the in-flight
-                # window — rounds already dispatched keep their
-                # placement; only pending ones migrate.
-                from jepsen_tpu.parallel import elastic
-                costs = {i: elastic.key_cost(r, capacity)
-                         for i, r in zip(chunk_idxs, rs)}
-                lf = {i: (r.get("stats") or {}).get("load-factor-peak")
-                      for i, r in zip(chunk_idxs, rs)}
-                sched.observe(costs, placement, lf=lf)
 
         for tier in sorted(buckets):
             idxs = buckets[tier]
@@ -716,42 +677,8 @@ def _stream(model, histories, capacity, max_capacity, mesh, bucket,
                 bstat["engine"] = "bitdense"
                 align = (1 if mesh is None
                          else int(mesh.shape[mesh.axis_names[0]]))
-                sched = None
-                if steal:
-                    from jepsen_tpu.parallel import elastic
-                    sched = elastic.KeyScheduler(
-                        idxs, n_dev=align,
-                        round_keys=max(1, max(1, chunk_keys)
-                                       // max(1, align)))
-                    bstat["steal"] = True
-                    scheds.append((bstat, sched))
-
-                def chunk_iter(idxs=idxs, sched=sched):
-                    # lazy on purpose: with the scheduler active, the
-                    # next round's composition must reflect every
-                    # rebalance a drain_one ran since the last one
-                    if sched is None:
-                        for chunk in _chunks(idxs, chunk_keys,
-                                             align=align):
-                            yield chunk, None
-                        return
-                    while True:
-                        placement = sched.next_round()
-                        if placement is None:
-                            return
-                        yield [i for i, _d in placement], placement
-
-                for chunk, placement in chunk_iter():
+                for chunk in _chunks(idxs, chunk_keys, align=align):
                     sub = [enc_of(i) for i in chunk]
-                    if sched is not None and align > 1 \
-                            and len(sub) % align:
-                        # the static _chunks path guarantees aligned
-                        # full chunks; scheduler rounds must too — a
-                        # ragged chunk would replicate every lane onto
-                        # every device. Pad lanes duplicate the last
-                        # key; drain_one's zip drops their results.
-                        sub = sub + [sub[-1]] * (align
-                                                 - len(sub) % align)
                     # pad every chunk to the BUCKET's (S, C, R): the
                     # closure gating resolves as the whole bucket
                     # would (the parity tests rely on this) and every
@@ -779,47 +706,16 @@ def _stream(model, histories, capacity, max_capacity, mesh, bucket,
                                     search_stats=search_stats))
                     except sup.DISPATCH_FAILURES as err:
                         degrade_chunk(chunk, err, bstat)
-                        if sched is not None:
-                            sched.observe({}, placement)
                         bstat["chunks"] += 1
                         reg.counter("pipeline.chunks").inc()
                         continue
                     pending.append((chunk, pb, bstat, bstat["chunks"],
-                                    t_issue, sched, placement))
+                                    t_issue))
                     bstat["chunks"] += 1
                     reg.counter("pipeline.chunks").inc()
                     _depth(len(pending))
                     while len(pending) >= depth:
                         drain_one()
-            elif steal:
-                # sparse tail under the stealer: the elastic round
-                # executor owns the ladder — device-aligned rounds,
-                # observed-cost rebalancing, identical results
-                # (parallel.elastic's parity contract)
-                from jepsen_tpu.parallel import elastic
-                bstat["engine"] = "sparse"
-                bstat["steal"] = True
-                est: dict = {}
-                sub = [enc_of(i) for i in idxs]
-                with obs.span("pipeline.sparse", tier=tier,
-                              keys=len(idxs)):
-                    rs = elastic.check_batch_stealing(
-                        model, sub, capacity=capacity,
-                        max_capacity=max_capacity, mesh=mesh,
-                        bucket=bucket, dedupe=dedupe,
-                        sparse_pallas=sparse_pallas,
-                        search_stats=search_stats,
-                        config_pack=config_pack, reshard=reshard,
-                        stats=est)
-                bstat["chunks"] = sum(b.get("rounds", 0)
-                                      for b in est.get("buckets", []))
-                reg.counter("pipeline.chunks").inc(
-                    max(1, bstat["chunks"]))
-                if steal_stats is not None:
-                    steal_stats.setdefault("buckets", []).extend(
-                        est.get("buckets", []))
-                for i, r in zip(idxs, rs):
-                    out[i] = r
             else:
                 # sparse tail: the per-key capacity-retry ladder is
                 # host-interactive, so it runs whole and synchronous —
@@ -840,11 +736,6 @@ def _stream(model, histories, capacity, max_capacity, mesh, bucket,
                     out[i] = r
         while pending:
             drain_one()
-        if steal_stats is not None:
-            for bstat_s, sched_s in scheds:
-                steal_stats.setdefault("buckets", []).append(
-                    {"tier": bstat_s["tier"], "engine": "bitdense",
-                     "keys": bstat_s["keys"], **sched_s.stats()})
 
         for bstat in bstats:
             bstat["encode_secs"] = round(sum(

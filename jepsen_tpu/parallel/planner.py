@@ -1,7 +1,7 @@
 """The self-tuning strategy planner (``JEPSEN_TPU_AUTO``).
 
-ROADMAP item 2's ONLINE half: the engine has ~6 orthogonal strategy
-axes (dedupe sort|hash, pallas closure, config pack, pipeline, steal)
+ROADMAP item 2's ONLINE half: the engine has four orthogonal strategy
+axes (dedupe sort|hash, pallas closure, config pack, pipeline)
 and peak speed used to require an operator who knows the whole flag
 table. With ``JEPSEN_TPU_AUTO=1`` the engines ask this module, per
 slot-window bucket, for the strategy vector to run — chosen from a
@@ -13,8 +13,7 @@ small per-shape decision table that is
             seed loader),
   updated   online from the per-dispatch secs/shape/strategy evidence
             the engines already measure (EWMA per shape×strategy
-            cell — the same smoothing ``elastic.KeyScheduler`` uses,
-            via :func:`ewma_update`),
+            cell, via :func:`ewma_update`),
   explored  occasionally (every ``JEPSEN_TPU_AUTO_EXPLORE``-th
             decision per shape, default 8, 0 = off): the
             least-sampled non-chosen arm runs instead, so a table
@@ -68,14 +67,13 @@ _log = logging.getLogger(__name__)
 
 TABLE_VERSION = 1
 DEFAULT_EXPLORE_EVERY = 8
-#: the EWMA smoothing the elastic scheduler settled on — shared via
-#: :func:`ewma_update` so the planner's cost cells and the stealing
-#: scheduler's cohort predictions decay identically
+#: the EWMA smoothing of the planner's per-shape cost cells
+#: (:func:`ewma_update`): each new observation weighs half
 EWMA_ALPHA = 0.5
 
 #: the strategy axes a plan may set, and the env flag each falls back
 #: to below the floor (the axis vocabulary of the dispatch records)
-AXES = ("dedupe", "pallas", "pack", "pipeline", "steal")
+AXES = ("dedupe", "pallas", "pack", "pipeline")
 
 #: default perf_ab evidence dir for seeding — the same default
 #: ``jepsen report --plan`` resolves
@@ -85,8 +83,8 @@ DEFAULT_BENCH_DIR = "bench_results"
 def ewma_update(prev: Optional[float], cost: float,
                 alpha: float = EWMA_ALPHA) -> float:
     """One exponentially-weighted update: ``alpha`` weights the NEW
-    observation (``elastic.KeyScheduler``'s convention). First
-    observation (prev None) adopts the cost outright."""
+    observation. First observation (prev None) adopts the cost
+    outright."""
     if prev is None:
         return float(cost)
     return alpha * float(cost) + (1.0 - alpha) * float(prev)
@@ -129,8 +127,7 @@ def _static_default(axis: str):
                                    what="dedupe strategy")
     flag = {"pallas": "JEPSEN_TPU_SPARSE_PALLAS",
             "pack": "JEPSEN_TPU_CONFIG_PACK",
-            "pipeline": "JEPSEN_TPU_PIPELINE",
-            "steal": "JEPSEN_TPU_STEAL"}[axis]
+            "pipeline": "JEPSEN_TPU_PIPELINE"}[axis]
     return envflags.env_bool(flag, default=False)
 
 
@@ -158,8 +155,6 @@ def _arm_from_detail(detail: dict) -> dict:
         arm["pack"] = bool(detail["pack"])
     if "depth" in detail:
         arm["pipeline"] = True
-    if "steal" in detail:
-        arm["steal"] = bool(detail["steal"])
     return arm
 
 

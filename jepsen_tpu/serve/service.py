@@ -193,10 +193,9 @@ class _Key:
         # ownership moved to another replica (rehome/migration) —
         # submit/result/finalize answer a structured refusal instead
         # of letting this replica become a second writer
-        self.device = None  # elastic device pin (steal_key): when
-        # set, this key's session places its scans here instead of
-        # the service-wide device — the in-process half of key
-        # work-stealing (JEPSEN_TPU_STEAL)
+        self.device = None  # device pin (steal_key): when set, this
+        # key's session places its scans here instead of the
+        # service-wide device
 
 
 class _TenantState:
@@ -1790,9 +1789,7 @@ class CheckerService:
         return True
 
     def steal_key(self, key, device=None) -> bool:
-        """Migrate a mid-stream key's device placement — the serve
-        half of elastic key work-stealing (JEPSEN_TPU_STEAL /
-        docs/performance.md "Elastic scheduling"): an external
+        """Migrate a mid-stream key's device placement: an external
         scheduler that sees one device running hot (the per-key
         ``engine.search.*`` stats / ``serve.apply`` spans are the
         signal) moves whole KEYS, never mid-search state. With a

@@ -425,24 +425,20 @@ def test_bench_probe_refuses_a_cpu_only_backend(monkeypatch):
 
 
 def test_bench_elastic_advisory_lines_gated_on_flags(monkeypatch, capsys):
-    """The ISSUE 15 elastic advisories: with JEPSEN_TPU_STEAL /
-    JEPSEN_TPU_RESHARD unset, emit_steal_advisory and
-    emit_reshard_advisory are no-ops BEFORE touching any argument or
+    """The elastic re-shard advisory: with JEPSEN_TPU_RESHARD unset,
+    emit_reshard_advisory is a no-op BEFORE touching any argument or
     backend — the default bench schema stays byte-identical (the
     emit_search_stats gating precedent above)."""
     import bench
 
-    monkeypatch.delenv("JEPSEN_TPU_STEAL", raising=False)
-    bench.emit_steal_advisory("testsec")
-    assert _json_lines(capsys.readouterr().out) == []
     monkeypatch.delenv("JEPSEN_TPU_RESHARD", raising=False)
     # args deliberately unusable: the gate must return first
     bench.emit_reshard_advisory(None, None, 0, 0, {}, 0.0)
     assert _json_lines(capsys.readouterr().out) == []
     # a malformed flag value raises (the envflags contract), never a
     # silent skip
-    monkeypatch.setenv("JEPSEN_TPU_STEAL", "maybe")
+    monkeypatch.setenv("JEPSEN_TPU_RESHARD", "maybe")
     import pytest as _pytest
     from jepsen_tpu.envflags import EnvFlagError
     with _pytest.raises(EnvFlagError):
-        bench.emit_steal_advisory("testsec")
+        bench.emit_reshard_advisory(None, None, 0, 0, {}, 0.0)

@@ -1,11 +1,9 @@
-"""Elastic mesh scheduling (ISSUE 15): the skew-driven key
-work-stealer (JEPSEN_TPU_STEAL, parallel.elastic), the
-re-shard-on-escalation ladder (JEPSEN_TPU_RESHARD,
-sharded.check_encoded_sharded_elastic), and the serve/stream key
-migration primitives. The deterministic parity suite rides tier-1; the
-forced-skew wall-clock A/B and the 2-D promotion integration are
-slow-marked (minutes of sparse CPU searches — the fast pins here cover
-the same code paths at small shapes)."""
+"""Elastic mesh work: the re-shard-on-escalation ladder
+(JEPSEN_TPU_RESHARD, sharded.check_encoded_sharded_elastic), the
+serve/stream key migration primitives (CheckerService.steal_key,
+HistorySession.migrate), and the search report's per-device skew
+column. The 2-D promotion integration is slow-marked (multi-minute CPU
+compiles)."""
 
 import numpy as np
 import pytest
@@ -13,20 +11,15 @@ import pytest
 import jax
 from jax.sharding import Mesh
 
-from jepsen_tpu import envflags
 from jepsen_tpu.histories import (adversarial_register_history,
-                                  corrupt_history, rand_fifo_history,
-                                  rand_gset_history, rand_queue_history,
                                   rand_register_history)
-from jepsen_tpu.models import (CASRegister, FIFOQueue, GSet, Mutex,
-                               UnorderedQueue)
-from jepsen_tpu.parallel import elastic, encode as enc_mod, engine
+from jepsen_tpu.models import CASRegister
+from jepsen_tpu.parallel import encode as enc_mod, engine
 from jepsen_tpu.parallel import sharded
-from jepsen_tpu.parallel.elastic import KeyScheduler
 
-# the order-independent result fields that must not move under any
-# scheduling decision (the ISSUE 15 parity pin set)
-PIN = elastic.STEAL_PIN
+# the result fields a device migration must not move
+PIN = ("valid?", "op", "fail-event", "max-frontier", "capacity",
+       "configs-stepped", "dedupe")
 
 
 def _pin(r):
@@ -35,223 +28,6 @@ def _pin(r):
 
 def _mesh():
     return Mesh(np.array(jax.devices()), ("key",))
-
-
-# ------------------------------------------------------ scheduler unit
-
-
-def test_scheduler_static_placement_and_rounds():
-    """Seed queues are contiguous blocks (the static sharded key-axis
-    placement) and rounds issue device-major; with steal=False nothing
-    ever migrates."""
-    s = KeyScheduler(list(range(8)), n_dev=4, round_keys=1,
-                     steal=False)
-    assert [list(q) for q in s.queues] == [[0, 1], [2, 3], [4, 5],
-                                           [6, 7]]
-    p1 = s.next_round()
-    assert p1 == [(0, 0), (2, 1), (4, 2), (6, 3)]
-    s.observe({0: 100.0, 2: 1.0, 4: 1.0, 6: 1.0}, p1)
-    # static: the hot cohort's remaining key stays home
-    assert [list(q) for q in s.queues] == [[1], [3], [5], [7]]
-    assert s.steals == 0
-    p2 = s.next_round()
-    assert p2 == [(1, 0), (3, 1), (5, 2), (7, 3)]
-    assert s.next_round() is None
-    st = s.stats()
-    assert st["rounds"] == 2 and st["steals"] == 0
-    assert st["per_device_cost"][0] == 100.0
-
-
-def test_scheduler_rebalance_concentrates_hot_cohort():
-    """After observing one hot cohort, the stealer deals the pending
-    keys back round-major by predicted cost: the hot device's backlog
-    spreads across ALL devices into the earliest rounds instead of
-    straggling one lane per round."""
-    # device 0's cohort = keys 0..3 (heavy), rest light
-    s = KeyScheduler(list(range(16)), n_dev=4, round_keys=1)
-    p1 = s.next_round()
-    assert p1 == [(0, 0), (4, 1), (8, 2), (12, 3)]
-    s.observe({0: 100.0, 4: 1.0, 8: 1.0, 12: 1.0}, p1)
-    # pending heavy keys 1,2,3 (cohort 0, predicted 100) must fill the
-    # NEXT round together, spread over devices
-    p2 = s.next_round()
-    assert [i for i, _d in p2][:3] == [1, 2, 3]
-    assert s.steals > 0
-    st = s.stats()
-    assert st["cohort_pred"][0] == 100.0
-    # deterministic: same observations -> same placement
-    s2 = KeyScheduler(list(range(16)), n_dev=4, round_keys=1)
-    q1 = s2.next_round()
-    s2.observe({0: 100.0, 4: 1.0, 8: 1.0, 12: 1.0}, q1)
-    assert s2.next_round() == p2
-
-
-def test_scheduler_unobserved_keeps_static_placement():
-    """No cost signal (e.g. a bitdense bucket with search stats off)
-    means no rebalancing — never fabricate a prediction."""
-    s = KeyScheduler(list(range(8)), n_dev=4, round_keys=1)
-    p1 = s.next_round()
-    s.observe({}, p1)
-    assert s.next_round() == [(1, 0), (3, 1), (5, 2), (7, 3)]
-    assert s.steals == 0
-
-
-def test_key_cost_signal_preference():
-    # stats block wins over counters; counters over nothing
-    assert elastic.key_cost({"capacity": 64, "configs-stepped": 10},
-                            64) == 64 + 10
-    tiered = elastic.key_cost(
-        {"capacity": 256, "configs-stepped": 10}, 64)
-    assert tiered == 3 * 256 + 10      # two doublings -> 3x weight
-    with_stats = elastic.key_cost(
-        {"capacity": 64, "configs-stepped": 10,
-         "stats": {"closure-iters": [2, 3]}}, 64)
-    assert with_stats == 64 * (5 + 2)
-    assert elastic.key_cost({"valid?": True}, 64) is None
-
-
-# ---------------------------------------------------- parity (tier-1)
-
-
-FAMILIES = [
-    ("cas-register", CASRegister,
-     lambda: rand_register_history(n_ops=40, n_processes=5, n_values=3,
-                                   crash_p=0.06, fail_p=0.08, seed=31)),
-    ("gset", GSet,
-     lambda: rand_gset_history(n_ops=36, n_processes=4, n_elements=9,
-                               crash_p=0.06, seed=33)),
-    ("uqueue", UnorderedQueue,
-     lambda: rand_queue_history(n_ops=26, n_processes=4, n_values=3,
-                                crash_p=0.06, seed=34)),
-    ("fifo", FIFOQueue,
-     lambda: rand_fifo_history(n_ops=24, n_processes=4, n_values=3,
-                               crash_p=0.05, seed=35)),
-]
-
-
-@pytest.mark.parametrize("name,Model,gen", FAMILIES,
-                         ids=[c[0] for c in FAMILIES])
-@pytest.mark.parametrize("dedupe", ["sort", "hash"])
-def test_steal_parity_clean_and_corrupted(name, Model, gen, dedupe):
-    """The ISSUE 15 parity pin: stealing on vs off (and vs the static
-    executor) is bit-identical in verdict/op/fail-event/max-frontier/
-    capacity/configs-stepped across the packable families,
-    clean+corrupted, both dedupe strategies."""
-    h = gen()
-    model = Model()
-    pres = []
-    for variant in (h, corrupt_history(h, seed=7, n_corruptions=2)):
-        try:
-            pres.append(enc_mod.encode(model, variant))
-        except enc_mod.EncodeError:
-            continue
-    if not pres:
-        pytest.skip("family/shape not device-encodable")
-    # a batch wide enough for two rounds on the 8-way mesh
-    # K=8 exactly: divisible by the mesh so no ragged replicated
-    # round compiles its own program (compile budget, not semantics)
-    pre = (pres * 8)[:8]
-    mesh = _mesh()
-    ref = engine.check_batch_encoded(model, pre, capacity=128,
-                                     mesh=mesh, dedupe=dedupe)
-    on = elastic.check_batch_stealing(model, pre, capacity=128,
-                                      mesh=mesh, dedupe=dedupe)
-    off = elastic.check_batch_stealing(model, pre, capacity=128,
-                                       mesh=mesh, dedupe=dedupe,
-                                       steal=False)
-    assert [_pin(r) for r in on] == [_pin(r) for r in ref]
-    assert [_pin(r) for r in off] == [_pin(r) for r in ref]
-
-
-def test_steal_parity_mutex_invalid_and_packed():
-    """Invalid verdicts and the packed configuration word through the
-    stealer: same counterexample localization, packed + unpacked."""
-    from jepsen_tpu.history import History, invoke_op, ok_op
-    h = History.wrap([
-        invoke_op(0, "acquire", None), ok_op(0, "acquire", None),
-        invoke_op(1, "acquire", None), ok_op(1, "acquire", None),
-    ]).index()
-    e = enc_mod.encode(Mutex(), h)
-    pre = [e] * 8
-    mesh = _mesh()
-    for pack in (False, True):
-        ref = engine.check_batch_encoded(Mutex(), pre, capacity=64,
-                                         mesh=mesh, config_pack=pack)
-        got = elastic.check_batch_stealing(Mutex(), pre, capacity=64,
-                                           mesh=mesh, config_pack=pack)
-        assert [_pin(r) for r in got] == [_pin(r) for r in ref]
-        assert got[0]["valid?"] is False
-
-
-def test_steal_capacity_ladder_parity_per_key():
-    """Per-key capacities are placement-independent: a heavy key lands
-    the same escalated tier whether it shares its round with light
-    keys or not (the round executor's ladder is the contract twin of
-    _check_batch_sparse's)."""
-    model, hs = elastic.forced_skew_histories(n_heavy=2, n_light=6)
-    pre = [enc_mod.encode(model, h) for h in hs]
-    mesh = _mesh()
-    ref = engine.check_batch_encoded(model, pre,
-                                     capacity=elastic.SKEW_CAPACITY,
-                                     max_capacity=1 << 16, mesh=mesh)
-    st: dict = {}
-    got = elastic.check_batch_stealing(model, pre,
-                                       capacity=elastic.SKEW_CAPACITY,
-                                       max_capacity=1 << 16, mesh=mesh,
-                                       stats=st)
-    assert [_pin(r) for r in got] == [_pin(r) for r in ref]
-    # the heavy keys really escalated (otherwise this pins nothing)
-    assert max(r["capacity"] for r in got) > elastic.SKEW_CAPACITY
-    assert st["buckets"][0]["engine"] == "sparse"
-
-
-def test_check_batch_steal_routing_and_stats_guard():
-    model = CASRegister()
-    hs = [rand_register_history(n_ops=30, n_processes=4, n_values=3,
-                                crash_p=0.05, seed=40 + i)
-          for i in range(8)]
-    mesh = _mesh()
-    ref = engine.check_batch(model, hs, mesh=mesh)
-    st: dict = {}
-    got = engine.check_batch(model, hs, mesh=mesh, steal=True,
-                             steal_stats=st)
-    assert [_pin(r) for r in got] == [_pin(r) for r in ref]
-    assert st["steal"] is True and st["buckets"]
-    # the loud-misuse contract (the cache/pipeline_stats precedent) —
-    # on BOTH routes: the pipelined path must not silently leave the
-    # dict empty either
-    with pytest.raises(ValueError, match="steal_stats"):
-        engine.check_batch(model, hs, steal_stats={})
-    with pytest.raises(ValueError, match="steal_stats"):
-        engine.check_batch(model, hs, pipeline=True, cache=False,
-                           steal_stats={})
-    # ragged batches (K not a device multiple) stay parity-identical:
-    # scheduler rounds pad to alignment with discarded duplicate lanes
-    ragged = hs[:5]
-    ref_r = engine.check_batch(model, ragged, mesh=mesh)
-    got_r = engine.check_batch(model, ragged, mesh=mesh, steal=True)
-    assert [_pin(r) for r in got_r] == [_pin(r) for r in ref_r]
-
-
-def test_steal_env_flag_resolution(monkeypatch):
-    monkeypatch.delenv("JEPSEN_TPU_STEAL", raising=False)
-    assert engine._resolve_steal(None) is False
-    monkeypatch.setenv("JEPSEN_TPU_STEAL", "1")
-    assert engine._resolve_steal(None) is True
-    monkeypatch.setenv("JEPSEN_TPU_STEAL", "yes")
-    with pytest.raises(envflags.EnvFlagError):
-        engine._resolve_steal(None)
-    monkeypatch.delenv("JEPSEN_TPU_STEAL", raising=False)
-    monkeypatch.setenv("JEPSEN_TPU_RESHARD", "2")
-    with pytest.raises(envflags.EnvFlagError):
-        engine._resolve_reshard(None)
-    monkeypatch.delenv("JEPSEN_TPU_RESHARD", raising=False)
-    monkeypatch.setenv("JEPSEN_TPU_STEAL_ROUND", "0")
-    with pytest.raises(envflags.EnvFlagError):
-        elastic._resolve_round_keys(0)
-    monkeypatch.setenv("JEPSEN_TPU_STEAL_ROUND", "3")
-    assert elastic._resolve_round_keys(0) == 3
-    assert elastic._resolve_round_keys(5) == 5   # explicit arg wins
 
 
 # ----------------------------------------------------------- re-shard
@@ -445,28 +221,7 @@ def test_search_report_device_skew_column():
     assert skew_section.index("hot") < skew_section.index("flat")
 
 
-# --------------------------------------------------- slow wall-clock
-
-
-@pytest.mark.slow
-def test_forced_skew_wall_clock_win():
-    """THE acceptance pin (ISSUE 15): on the recorded forced-skew
-    8-fake-device shape, stealing beats the static placement by
-    >= 1.2x wall-clock with bit-identical verdicts (steal_ab asserts
-    the parity itself). Slow tier: ~60-90s of deliberate sparse CPU
-    searches — the parity/scheduler behavior is pinned fast above;
-    this guards the WIN against scheduler regressions."""
-    model, hs = elastic.forced_skew_histories()
-    pre = [enc_mod.encode(model, h) for h in hs]
-    ab = elastic.steal_ab(model, pre, _mesh())
-    assert ab["verdicts_identical"]
-    assert ab["steal_speedup"] >= 1.2, ab
-    b_steal = ab["steal"][0]
-    b_static = ab["static"][0]
-    assert b_steal["steals"] > 0
-    # the mesh really was idling under the static placement and the
-    # stealer measurably narrowed it
-    assert b_steal["busy_frac"] > b_static["busy_frac"]
+# ------------------------------------------------------- slow 2-D
 
 
 @pytest.mark.slow

@@ -290,18 +290,18 @@ def test_fuzz_sharded_hash_parity_on_mesh():
 
 @pytest.mark.fuzz
 def test_fuzz_elastic_engines_agree_with_wgl():
-    """Randomized differential for the ISSUE 15 elastic layer: the
-    stealing round executor (batched, key axis on the 8-way mesh) and
-    the re-shard sharded ladder must agree with the host WGL oracle on
-    clean and value-corrupted histories — scheduling and device
-    recruiting must never touch a verdict. Fixed op counts so the
-    compiled shapes repeat across seeds (the sharded-mesh sweep's
-    precedent)."""
+    """Randomized differential for the mesh-placed batch and the
+    elastic re-shard ladder: the static key-sharded batch (key axis on
+    the 8-way mesh) and the re-shard sharded ladder must agree with
+    the host WGL oracle on clean and value-corrupted histories —
+    placement and device recruiting must never touch a verdict. Fixed
+    op counts so the compiled shapes repeat across seeds (the
+    sharded-mesh sweep's precedent)."""
     import jax
     from jax.sharding import Mesh
 
     from jepsen_tpu.models import CASRegister
-    from jepsen_tpu.parallel import elastic, engine, sharded
+    from jepsen_tpu.parallel import engine, sharded
 
     mesh = Mesh(np.array(jax.devices()), ("key",))
     model = CASRegister()
@@ -321,23 +321,15 @@ def test_fuzz_elastic_engines_agree_with_wgl():
                 model, h, max_states=1_000_000,
                 deadline=monotonic() + 8)["valid?"])
         pre = [enc_mod.encode(model, h) for h in hs]
-        rs = elastic.check_batch_stealing(model, pre, capacity=128,
-                                          max_capacity=1 << 15,
-                                          mesh=mesh)
-        static = engine.check_batch_encoded(model, pre, capacity=128,
-                                            max_capacity=1 << 15,
-                                            mesh=mesh)
-        for j, (r, s, oracle) in enumerate(zip(rs, static, oracles)):
+        rs = engine.check_batch_encoded(model, pre, capacity=128,
+                                        max_capacity=1 << 15,
+                                        mesh=mesh)
+        for j, (r, oracle) in enumerate(zip(rs, oracles)):
             if oracle == "unknown" or r["valid?"] == "unknown":
                 continue
             runs += 1
             if r["valid?"] is not oracle:
-                failures.append(("steal-oracle", seed, j, oracle, r))
-            if r["valid?"] != s["valid?"] \
-                    or r.get("capacity") != s.get("capacity") \
-                    or r.get("configs-stepped") != \
-                    s.get("configs-stepped"):
-                failures.append(("steal-static", seed, j, s, r))
+                failures.append(("batch-oracle", seed, j, oracle, r))
         # the elastic sharded ladder vs the oracle on one key per seed
         e0 = pre[0]
         re = sharded.check_encoded_sharded_elastic(

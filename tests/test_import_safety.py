@@ -19,10 +19,9 @@ ENGINE_MODULES = [
     "jepsen_tpu.parallel.sharded",
     "jepsen_tpu.parallel.pallas_kernels",
     "jepsen_tpu.parallel.extend",
-    # the elastic scheduling layer: the scheduler and the mesh planner
-    # must import (and plan) without touching a backend — the gated
-    # jax.distributed handshake only runs inside distributed_init
-    "jepsen_tpu.parallel.elastic",
+    # the mesh planner must import (and plan) without touching a
+    # backend — the gated jax.distributed handshake only runs inside
+    # distributed_init
     "jepsen_tpu.parallel.meshplan",
     "jepsen_tpu.models",
     "jepsen_tpu.independent",

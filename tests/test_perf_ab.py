@@ -220,7 +220,9 @@ def test_perf_ab_elastic_unknown_arm_raises():
     assert r.returncode != 0, r.stdout[-500:]
     assert "unknown arm" in r.stderr, r.stderr[-500:]
     assert "reshards" in r.stderr
-    assert "steal,reshard" in r.stderr, r.stderr[-500:]
+    # steal is not an arm; the valid set names reshard alone
+    assert "'steal'" in r.stderr, r.stderr[-500:]
+    assert "valid: reshard" in r.stderr, r.stderr[-500:]
 
 
 def _load_perf_ab():

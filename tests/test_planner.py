@@ -126,9 +126,9 @@ def test_exploration_cadence_is_deterministic():
     assert obs.counter("engine.plan.explorations").value == before + 1
 
 
-def test_ewma_matches_elastic_smoothing():
-    # planner cells and the stealing scheduler's cohort predictions
-    # share one estimator (docs/performance.md "Auto planner")
+def test_ewma_update_matches_planner_cells():
+    # the planner's cost cells fold observations through ewma_update
+    # (docs/performance.md "Auto planner")
     assert planner.ewma_update(None, 0.1) == pytest.approx(0.1)
     assert planner.ewma_update(0.1, 0.2) == pytest.approx(0.15)
     pl = _mem_planner(floor=1)
@@ -255,7 +255,7 @@ def test_auto_check_batch_plans_executor_axes(monkeypatch):
     grp = tbl[planner.group_key("batch", "CASRegister", None)]
     assert grp["decisions"] >= 1
     (cell,) = grp["cells"].values()
-    assert cell["arm"] == {"pipeline": False, "steal": False}
+    assert cell["arm"] == {"pipeline": False}
     assert cell["n_live"] == 1
 
 
@@ -327,21 +327,6 @@ def test_plan_endpoint_off_and_on(monkeypatch):
         assert cells["dedupe=hash"]["n"] == 1
     finally:
         srv.close()
-
-
-def test_elastic_ewma_cost_gauge():
-    from jepsen_tpu.parallel import elastic
-    ks = elastic.KeyScheduler(range(4), n_dev=2, round_keys=2,
-                              steal=True)
-    placement = ks.next_round()
-    ks.observe({i: 0.1 * (i + 1) for i, _ in placement})
-    # cohort 0 saw keys 0 and 1 (0.1 then 0.2): the planner's shared
-    # estimator folds them to 0.15, published per cohort on /metrics
-    assert ks.pred[0] == pytest.approx(
-        planner.ewma_update(planner.ewma_update(None, 0.1), 0.2))
-    snap = obs.registry().snapshot()
-    g = snap[obs.labeled("elastic.ewma_cost", cohort="0")]
-    assert g["value"] == pytest.approx(0.15)
 
 
 # ------------------------------------------------------- convergence

@@ -80,19 +80,15 @@ if _UNKNOWN_D:
                      f"{sorted(_UNKNOWN_D)}; valid: "
                      f"{','.join(_DEDUPE_VALID)}")
 
-# PERF_AB_ELASTIC=steal,reshard (default both) selects the elastic-
-# scheduling arms — the recorded A/B the JEPSEN_TPU_STEAL and
-# JEPSEN_TPU_RESHARD flip decisions wait on: "steal" runs the pinned
-# forced-skew multikey shape (parallel.elastic.forced_skew_histories)
-# through the round executor with the scheduler off then on, plus an
-# untimed stats-armed pass per arm whose search_stats record captures
-# the BEFORE/AFTER per-device load-factor spread; "reshard" times the
-# grow-the-table sharded ladder against the device-recruiting one on
-# an escalating adversarial shape. Same validation posture as the
-# other selector envs: a typo raises with the valid set listed.
-_ELASTIC_VALID = ("steal", "reshard")
+# PERF_AB_ELASTIC=reshard (the default; empty skips it) selects the
+# elastic arm — the recorded A/B the JEPSEN_TPU_RESHARD flip decision
+# waits on: "reshard" times the grow-the-table sharded ladder against
+# the device-recruiting one on an escalating adversarial shape. Same
+# validation posture as the other selector envs: a typo raises with
+# the valid set listed.
+_ELASTIC_VALID = ("reshard",)
 _ELASTIC = [v.strip() for v in os.environ.get(
-    "PERF_AB_ELASTIC", "steal,reshard").split(",") if v.strip()]
+    "PERF_AB_ELASTIC", "reshard").split(",") if v.strip()]
 _UNKNOWN_E = set(_ELASTIC) - set(_ELASTIC_VALID)
 if _UNKNOWN_E:
     raise SystemExit(f"PERF_AB_ELASTIC: unknown arm(s) "
@@ -651,104 +647,51 @@ def main():
               "auto_plan": auto_plan,
               "auto_mismatch": auto_bad})
 
-    # ---- elastic scheduling (steal / reshard arms) ----
-    steal_ratios = {}
+    # ---- elastic re-sharding (reshard arm) ----
     reshard_ratios = {}
-    elastic_bad = set()
-    if _ELASTIC:
+    reshard_bad = False
+    if "reshard" in _ELASTIC:
         import numpy as _np
         from jax.sharding import Mesh as _Mesh
-        from jepsen_tpu.parallel import elastic as el_mod
         mesh_all = _Mesh(_np.array(jax.devices()), ("key",))
-        if "steal" in _ELASTIC:
-            n_h, n_l = (4, 12) if smoke else (8, 40)
-            model_sk, hs_sk = el_mod.forced_skew_histories(
-                n_heavy=n_h, n_light=n_l)
-            pre_sk = [enc_mod.encode(model_sk, h) for h in hs_sk]
-            shape_key = f"multikey-skew-{n_h}h{n_l}l"
-            try:
-                ab = el_mod.steal_ab(model_sk, pre_sk, mesh_all)
-            except AssertionError:
-                # the A/B's own parity gate fired: scheduling changed
-                # a result — vetoes the verdict like any mismatch
-                elastic_bad.add("steal")
-                emit({"steal_mismatch": True, "shape": shape_key})
-            else:
-                steal_ratios[shape_key] = ab["steal_speedup"]
-                b_s, b_e = ab["static"][0], ab["steal"][0]
-                emit({"shape": shape_key,
-                      "static_secs": ab["static_secs"],
-                      "steal_secs": ab["steal_secs"],
-                      "steal_speedup": round(ab["steal_speedup"], 2),
-                      "keys_stolen": b_e.get("steals"),
-                      "busy_frac_static": b_s.get("busy_frac"),
-                      "busy_frac_steal": b_e.get("busy_frac")})
-                # the per-shape search_stats evidence record: one
-                # UNTIMED stats-armed hash-dedupe pass per arm — the
-                # before/after per-device load-factor spread the flag-
-                # flip campaign reads; never part of the flip decision
-                try:
-                    ev = {}
-                    for arm, name in ((False, "static"),
-                                      (True, "steal")):
-                        st = {}
-                        el_mod.check_batch_stealing(
-                            model_sk, pre_sk,
-                            capacity=el_mod.SKEW_CAPACITY,
-                            max_capacity=1 << 16, mesh=mesh_all,
-                            steal=arm, dedupe="hash",
-                            search_stats=True, stats=st)
-                        b = st["buckets"][0]
-                        ev[f"per_device_load_factor_{name}"] = \
-                            b.get("per_device_load_factor_peak")
-                        ev[f"load_factor_spread_{name}"] = \
-                            b.get("load_factor_spread")
-                        ev[f"per_device_cost_{name}"] = \
-                            b.get("per_device_cost")
-                    emit({"search_stats": ev, "shape": shape_key})
-                except Exception as err:  # noqa: BLE001 — advisory
-                    emit({"search_stats_error": repr(err),
-                          "shape": shape_key})
-        if "reshard" in _ELASTIC:
-            from jepsen_tpu.parallel import sharded as sh_mod
-            L_r, k_r = (200, 6) if smoke else (1000, 8)
-            e_r = enc_mod.encode(model, adversarial_register_history(
-                n_ops=L_r, k_crashed=k_r, seed=7))
-            cap_r = 128    # well under the ~10*2^k peak: both arms
-            # must climb their ladders — that climb IS the measurement
-            shape_key = f"sharded-reshard-{L_r}@2^{k_r}"
-            res_r = {}
-            t_st = _timed(res_r, "static",
-                          # reshard pinned OFF: an exported
-                          # JEPSEN_TPU_RESHARD=1 must not delegate
-                          # the static arm to the elastic ladder and
-                          # A/B it against itself (the steal arm pins
-                          # steal=arm the same way)
-                          lambda: sh_mod.check_encoded_sharded(
-                              e_r, mesh_all, capacity=cap_r,
-                              max_capacity=1 << 16, reshard=False),
-                          shape=shape_key)
-            t_el = _timed(res_r, "reshard",
-                          lambda: sh_mod.check_encoded_sharded_elastic(
-                              e_r, mesh_all, capacity=cap_r,
-                              max_capacity=1 << 16),
-                          shape=shape_key)
-            pin_r = lambda r: {k_: r.get(k_) for k_ in  # noqa: E731
-                               ("valid?", "op", "fail-event",
-                                "max-frontier")}
-            base_r = pin_r(res_r["static"][0])
-            if any(pin_r(r) != base_r for r in res_r["reshard"]):
-                elastic_bad.add("reshard")
-                emit({"reshard_mismatch": True, "shape": shape_key})
-            reshard_ratios[shape_key] = t_st / max(t_el, 1e-9)
-            emit({"shape": shape_key,
-                  "static_secs": round(t_st, 3),
-                  "reshard_secs": round(t_el, 3),
-                  "reshard_speedup": round(
-                      reshard_ratios[shape_key], 2),
-                  "reshard_events": (res_r["reshard"][0].get("reshard")
-                                     or {}).get("events"),
-                  "devices_final": res_r["reshard"][0].get("devices")})
+        from jepsen_tpu.parallel import sharded as sh_mod
+        L_r, k_r = (200, 6) if smoke else (1000, 8)
+        e_r = enc_mod.encode(model, adversarial_register_history(
+            n_ops=L_r, k_crashed=k_r, seed=7))
+        cap_r = 128    # well under the ~10*2^k peak: both arms
+        # must climb their ladders — that climb IS the measurement
+        shape_key = f"sharded-reshard-{L_r}@2^{k_r}"
+        res_r = {}
+        t_st = _timed(res_r, "static",
+                      # reshard pinned OFF: an exported
+                      # JEPSEN_TPU_RESHARD=1 must not delegate
+                      # the static arm to the elastic ladder and
+                      # A/B it against itself
+                      lambda: sh_mod.check_encoded_sharded(
+                          e_r, mesh_all, capacity=cap_r,
+                          max_capacity=1 << 16, reshard=False),
+                      shape=shape_key)
+        t_el = _timed(res_r, "reshard",
+                      lambda: sh_mod.check_encoded_sharded_elastic(
+                          e_r, mesh_all, capacity=cap_r,
+                          max_capacity=1 << 16),
+                      shape=shape_key)
+        pin_r = lambda r: {k_: r.get(k_) for k_ in  # noqa: E731
+                           ("valid?", "op", "fail-event",
+                            "max-frontier")}
+        base_r = pin_r(res_r["static"][0])
+        if any(pin_r(r) != base_r for r in res_r["reshard"]):
+            reshard_bad = True
+            emit({"reshard_mismatch": True, "shape": shape_key})
+        reshard_ratios[shape_key] = t_st / max(t_el, 1e-9)
+        emit({"shape": shape_key,
+              "static_secs": round(t_st, 3),
+              "reshard_secs": round(t_el, 3),
+              "reshard_speedup": round(
+                  reshard_ratios[shape_key], 2),
+              "reshard_events": (res_r["reshard"][0].get("reshard")
+                                 or {}).get("events"),
+              "devices_final": res_r["reshard"][0].get("devices")})
 
     # ---- multi-key batch ----
     n_keys, ops_per_key = (8, 40) if smoke else (84, 120)
@@ -912,11 +855,8 @@ def main():
                                "timings don't flip defaults; the "
                                "gate_coverage records stand on any "
                                "backend)")
-        steal_verdict = ("no-verdict (non-tpu backend: cpu timings "
-                         "don't flip defaults; the forced-skew win "
-                         "and the per-device spread records stand "
-                         "on any backend)")
-        reshard_verdict = steal_verdict
+        reshard_verdict = ("no-verdict (non-tpu backend: cpu "
+                           "timings don't flip defaults)")
         auto_verdict = ("not-measured (PERF_AB_AUTO=0)"
                         if _AUTO != "1" else
                         "no-verdict (non-tpu backend; advisory either "
@@ -986,21 +926,10 @@ def main():
                 if config_pack_ratios
                 and min(config_pack_ratios.values()) >= 1.1
                 else "keep-opt-in")
-        if "steal" not in _ELASTIC:
-            steal_verdict = "not-measured (steal skipped by " \
-                            "PERF_AB_ELASTIC)"
-        elif "steal" in elastic_bad:
-            steal_verdict = ("keep-opt-in (ARM VETOED — scheduling "
-                             "changed a result; see steal_mismatch)")
-        else:
-            steal_verdict = ("default-on"
-                             if steal_ratios
-                             and min(steal_ratios.values()) >= 1.1
-                             else "keep-opt-in")
         if "reshard" not in _ELASTIC:
             reshard_verdict = "not-measured (reshard skipped by " \
                               "PERF_AB_ELASTIC)"
-        elif "reshard" in elastic_bad:
+        elif reshard_bad:
             reshard_verdict = ("keep-opt-in (ARM VETOED — see "
                                "reshard_mismatch)")
         else:
@@ -1028,7 +957,6 @@ def main():
           "dedupe_verdict": dedupe_verdict,
           "sparse_pallas_verdict": sparse_pallas_verdict,
           "config_pack_verdict": config_pack_verdict,
-          "steal_verdict": steal_verdict,
           "reshard_verdict": reshard_verdict,
           "auto_verdict": auto_verdict,
           "auto_measured": _AUTO == "1",
@@ -1037,8 +965,6 @@ def main():
           "variants_measured": sorted(_VARIANTS),
           "dedupe_measured": sorted(_DEDUPE),
           "elastic_measured": sorted(_ELASTIC),
-          "steal_ratios": {k: round(v, 2)
-                           for k, v in steal_ratios.items()},
           "reshard_ratios": {k: round(v, 2)
                              for k, v in reshard_ratios.items()},
           "ratios": {k: round(v, 2) for k, v in ratios.items()},
@@ -1072,17 +998,11 @@ def main():
                   "JEPSEN_TPU_CONFIG_PACK's default "
                   "(engine._resolve_config_pack) likewise — the "
                   "gate_coverage lines record, per shape and layout, "
-                  "bytes/row and what would run, chip-free. steal "
-                  "(the skew-driven key work-stealer vs the static "
-                  "placement, same round executor) flips "
-                  "JEPSEN_TPU_STEAL's default "
-                  "(engine._resolve_steal) under the same "
-                  ">=1.1x-on-every-shape + never-disagreed rule; "
-                  "reshard (the device-recruiting sharded ladder vs "
-                  "the grow-the-table one) flips JEPSEN_TPU_RESHARD "
-                  "(engine._resolve_reshard) likewise — the "
-                  "search_stats lines record the before/after "
-                  "per-device load-factor spread per shape. The "
+                  "bytes/row and what would run, chip-free. reshard "
+                  "(the device-recruiting sharded ladder vs the "
+                  "grow-the-table one) flips JEPSEN_TPU_RESHARD "
+                  "(engine._resolve_reshard) under the same "
+                  ">=1.1x-on-every-shape + never-disagreed rule. The "
                   "PERF_AB_AUTO=1 arm (JEPSEN_TPU_AUTO planner "
                   "routing all axes) is ADVISORY under the same "
                   ">=1.1x / never-disagreed reading: it reports "
